@@ -16,6 +16,8 @@ def main() -> None:
                              "roofline", "engines", "trajectory"])
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks.common import header
     from benchmarks import (engine_parity, exp1_error, exp2_matvecs,
                             exp3_runtime, kernel_bench, roofline, trajectory)

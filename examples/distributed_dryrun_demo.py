@@ -15,6 +15,7 @@ import jax
 from repro.graphs import powerlaw_configuration
 from repro.core import heterogeneous, build_operators, power_psi
 from repro.core.distributed import DistributedPsi
+from repro.launch.mesh import make_mesh
 from repro.runtime import PsiDriver
 
 
@@ -23,7 +24,7 @@ def main():
     act = heterogeneous(g.n, seed=4)
     ref = power_psi(build_operators(g, act), tol=1e-9)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     dist = DistributedPsi.from_graph(g, act, mesh)
     print(f"partition imbalance (straggler indicator): "
           f"{dist.part.imbalance:.3f}")
@@ -39,7 +40,7 @@ def main():
     run = dist.make_run(chunk_iters=16)
     s_mid, _ = run(dist.arrays.c_src, dist.arrays)
     drv2 = PsiDriver(dist, chunk_iters=16).remesh(
-        jax.make_mesh((4, 2), ("data", "model")), g, act, s_mid)
+        make_mesh((4, 2), ("data", "model")), g, act, s_mid)
     d2 = drv2.dist
     run2 = d2.make_run(chunk_iters=16)
     s, gap, it = drv2._warm_s, np.inf, 16

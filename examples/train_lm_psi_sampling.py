@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.configs import get_arch
 from repro.core import heterogeneous, build_operators, power_psi
 from repro.graphs import powerlaw_configuration
+from repro.launch.mesh import make_mesh
 from repro.data import TokenPipeline, PsiWeightedSampler
 from repro.models.transformer import LMConfig, init_params, make_train_step
 from repro.train import adamw, cosine_schedule
@@ -49,7 +50,7 @@ def main():
     print("ψ-curation:", sampler.mixture_stats())
 
     # 2. model + substrate
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = LMConfig(name="psi-lm", n_layers=args.layers,
                    d_model=args.d_model, n_heads=max(2, args.d_model // 32),
                    n_kv_heads=max(1, args.d_model // 64), vocab=args.vocab,

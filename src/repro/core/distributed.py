@@ -31,8 +31,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
-
 from ..graphs.partition import Partition2D, partition_2d
 from ..graphs.structure import Graph
 from .activity import Activity
@@ -241,10 +239,10 @@ class DistributedPsi:
             partial_t = self._local_push(s, a, nc)
             return self._local_finish(partial_t, s, a, src_axes)
 
-        return shard_map(
+        return jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(P(src_axes, None), self._arr_specs()),
-            out_specs=(P(src_axes, None), P()))
+            out_specs=(P(src_axes, None), P()), check_vma=False)
 
     def make_dispatch(self):
         """Compute-only half: (s_src, arrays) → :class:`PartialReduction`.
@@ -261,12 +259,12 @@ class DistributedPsi:
             partial_t = self._local_push(s, a, nc)
             return PartialReduction(partial_t=partial_t[None, None], s_in=s)
 
-        return shard_map(
+        return jax.shard_map(
             local_dispatch, mesh=self.mesh,
             in_specs=(P(src_axes, None), self._arr_specs()),
             out_specs=PartialReduction(
                 partial_t=P(src_axes, "model", None),
-                s_in=P(src_axes, None)))
+                s_in=P(src_axes, None)), check_vma=False)
 
     def make_finalize(self):
         """Collective half: (:class:`PartialReduction`, arrays) →
@@ -277,12 +275,12 @@ class DistributedPsi:
         def local_finalize(h: PartialReduction, a: DistPsiArrays):
             return self._local_finish(h.partial_t[0, 0], h.s_in, a, src_axes)
 
-        return shard_map(
+        return jax.shard_map(
             local_finalize, mesh=self.mesh,
             in_specs=(PartialReduction(
                 partial_t=P(src_axes, "model", None),
                 s_in=P(src_axes, None)), self._arr_specs()),
-            out_specs=(P(src_axes, None), P()))
+            out_specs=(P(src_axes, None), P()), check_vma=False)
 
     def make_epilogue(self):
         """ψ from converged s: one more push, then (λ⊙t + d)/N, dst layout."""
@@ -296,10 +294,10 @@ class DistributedPsi:
             psi_piece = (a.lam_piece[0, 0] * t_piece + a.d_piece[0, 0]) / n
             return psi_piece[None, None]
 
-        return shard_map(
+        return jax.shard_map(
             local_epilogue, mesh=self.mesh,
             in_specs=(P(src_axes, None), self._arr_specs()),
-            out_specs=P(src_axes, "model", None))
+            out_specs=P(src_axes, "model", None), check_vma=False)
 
     # ------------------------------------------------------------------ #
     def make_run(self, *, chunk_iters: int = 8, unroll: bool = False):
@@ -425,11 +423,11 @@ class DistributedPsi1D:
         # this shard_map deadlocks the XLA CPU in-process communicator
         # (runtime quirk; compile is fine either way).
 
-        return shard_map(
+        return jax.shard_map(
             local_step, mesh=self.mesh,
             in_specs=(P(), P(self.axes, None), P(self.axes, None),
                       P(), P(), P()),
-            out_specs=P())
+            out_specs=P(), check_vma=False)
 
     def input_specs(self):
         sd = jax.ShapeDtypeStruct

@@ -1082,11 +1082,11 @@ class DistributedEngine(PsiEngine):
         self._epi = jax.jit(dist.make_epilogue())
 
     def prepare(self, graph: Graph, activity: Activity) -> EngineState:
+        from ..launch.mesh import make_mesh
         from .distributed import DistributedPsi
         self._base_prepare(graph, activity)
         if self.mesh is None:
-            self.mesh = jax.make_mesh((len(jax.devices()), 1),
-                                      ("data", "model"))
+            self.mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
         self._install_dist(DistributedPsi.from_graph(
             graph, activity, self.mesh, dtype=self.dtype))
         return EngineState(s=self.dist.arrays.c_src)

@@ -31,6 +31,7 @@ def _kernel(src_tile_ref, dst_tile_ref, first_ref, s_ref, tiles_ref, out_ref):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     out_ref[...] += jnp.dot(s_ref[...], tiles_ref[0],
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=out_ref.dtype)
 
 

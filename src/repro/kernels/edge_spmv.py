@@ -3,17 +3,20 @@
 TPU-native design (DESIGN.md §3): edges are pre-blocked so each block of
 ``e1 × e2`` edges writes a single output tile of ``tile`` nodes. Per block:
 
-  1. gather ``s_pre[src_idx]``          — VPU dynamic load, [e1, e2]
-  2. optional per-edge weights          — VPU multiply
-  3. scatter-by-one-hot                 — e1 × ([1, e2] @ [e2, tile]) MXU
+  1. gather ``s_pre[src_idx]`` (× optional per-edge weights) — XLA, before
+     the kernel; Mosaic lowers only 2-D gathers, and a gathered block keeps
+     VMEM independent of N
+  2. scatter-by-one-hot                 — e1 × ([1, e2] @ [e2, tile]) MXU
                                           mat-vecs accumulated into the
-                                          output tile resident in VMEM
+                                          output tile resident in VMEM, at
+                                          f32 contract precision (the
+                                          values are not rounded to bf16)
 
 The output BlockSpec revisits the same tile for consecutive blocks of one
 node tile (grid is ordered dst-major), so accumulation happens in VMEM and
 each output tile is written to HBM exactly once. VMEM footprint per step:
-s_pre (full shard) + 2·e1·e2 i32 + tile f32 — a few MB for N ≤ 10⁶ shards,
-sized for v5e VMEM with 128-lane / 8-sublane alignment.
+2·e1·e2 values/indices + tile f32, sized for v5e VMEM with 128-lane /
+8-sublane alignment.
 """
 from __future__ import annotations
 
@@ -27,33 +30,25 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["edge_spmv_call"]
 
 
-def _make_kernel(e1: int, tile: int, weighted: bool):
-    def kernel(block_tile_ref, first_ref, *refs):
-        if weighted:
-            s_ref, idx_ref, dstl_ref, w_ref, out_ref = refs
-        else:
-            s_ref, idx_ref, dstl_ref, out_ref = refs
-            w_ref = None
+def _make_kernel(e1: int, tile: int):
+    def kernel(block_tile_ref, first_ref, vals_ref, dstl_ref, out_ref):
         b = pl.program_id(0)
 
         @pl.when(first_ref[b] == 1)
         def _zero():
             out_ref[...] = jnp.zeros_like(out_ref)
 
-        s_vec = s_ref[0]                                  # [n_pad]
-        idx = idx_ref[0]                                  # [e1, e2] i32
-        gathered = jnp.take(s_vec, idx, axis=0)           # VPU gather
-        if w_ref is not None:
-            gathered = gathered * w_ref[0]
+        vals = vals_ref[0]                                # [e1, e2]
         dstl = dstl_ref[0]                                # [e1, e2] i32
-        e2 = idx.shape[1]
+        e2 = vals.shape[1]
         acc = out_ref[...]                                # [1, tile]
         for r in range(e1):                               # static unroll
             onehot = (dstl[r][:, None] ==
                       jax.lax.broadcasted_iota(jnp.int32, (e2, tile), 1)
-                      ).astype(s_vec.dtype)               # [e2, tile]
-            acc = acc + jnp.dot(gathered[r][None, :], onehot,
-                                preferred_element_type=s_vec.dtype)
+                      ).astype(vals.dtype)                # [e2, tile]
+            acc = acc + jnp.dot(vals[r][None, :], onehot,
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=vals.dtype)
         out_ref[...] = acc
 
     return kernel
@@ -61,43 +56,33 @@ def _make_kernel(e1: int, tile: int, weighted: bool):
 
 @functools.partial(jax.jit, static_argnames=("tile", "e1", "e2", "num_tiles",
                                              "interpret"))
-def edge_spmv_call(s_pre_pad: jax.Array, src_idx: jax.Array,
-                   dst_local: jax.Array, block_tile: jax.Array,
-                   block_first: jax.Array, weights: jax.Array | None = None,
+def edge_spmv_call(edge_vals: jax.Array, dst_local: jax.Array,
+                   block_tile: jax.Array, block_first: jax.Array,
                    *, tile: int, e1: int, e2: int, num_tiles: int,
                    interpret: bool = False) -> jax.Array:
     """Raw pallas_call over a pre-built EdgeTileFormat (arrays on device).
 
     Args:
-      s_pre_pad: f[1, n_gather] gather source; sentinel slots hold 0.
-      src_idx / dst_local: i32[num_blocks, e1, e2].
+      edge_vals: f[num_blocks, e1, e2] per-slot contributions (the gathered
+        ``s_pre[src_idx]``, already weighted); sentinel slots hold 0.
+      dst_local: i32[num_blocks, e1, e2].
       block_tile / block_first: i32[num_blocks] scalar-prefetch tables.
-      weights: optional f[num_blocks, e1, e2] per-edge weights.
 
     Returns:
       f[1, num_tiles * tile] scatter result; caller slices [:, :n].
     """
-    num_blocks = src_idx.shape[0]
-    in_specs = [
-        pl.BlockSpec((1, s_pre_pad.shape[1]), lambda b, *_: (0, 0)),
-        pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0)),
-        pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0)),
-    ]
-    inputs = [s_pre_pad, src_idx, dst_local]
-    if weights is not None:
-        in_specs.append(pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0)))
-        inputs.append(weights)
-
+    num_blocks = edge_vals.shape[0]
+    block = pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(num_blocks,),
-        in_specs=in_specs,
+        in_specs=[block, block],
         out_specs=pl.BlockSpec((1, tile), lambda b, bt, bf: (0, bt[b])),
     )
     return pl.pallas_call(
-        _make_kernel(e1, tile, weights is not None),
+        _make_kernel(e1, tile),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((1, num_tiles * tile),
-                                       s_pre_pad.dtype),
+                                       edge_vals.dtype),
         interpret=interpret,
-    )(block_tile, block_first, *inputs)
+    )(block_tile, block_first, edge_vals, dst_local)

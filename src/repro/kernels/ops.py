@@ -1,8 +1,9 @@
 """Public jit'd wrappers around the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only — the
-kernels are *targeted* at TPU v5e and *validated* in interpret mode, per
-DESIGN.md §8).
+``interpret`` defaults to True off-TPU: on a TPU the kernels compile through
+Mosaic; on the CPU (the test suite runs with ``JAX_PLATFORMS=cpu``) the same
+kernels run in Pallas interpret mode. Entry points that must not fall back
+to the CPU check the platform themselves (``chip_smoke.py``).
 """
 from __future__ import annotations
 
@@ -63,6 +64,11 @@ class DeviceEdgeTiles:
         """f[n] → f[1, n_gather] with zeros beyond n (sentinel = n)."""
         return jnp.pad(s_pre, (0, self.n_gather - s_pre.shape[0]))[None, :]
 
+    def gather_edges(self, s_pre_pad: jax.Array) -> jax.Array:
+        """f[1, n_gather] → f[num_blocks, e1, e2]: each slot's source value
+        (sentinel slots read the zero at index n)."""
+        return s_pre_pad[0][self.src_idx]
+
     def pad_node_vector(self, v: jax.Array) -> jax.Array:
         return jnp.pad(v, (0, self.n_pad - v.shape[0]))[None, :]
 
@@ -109,9 +115,11 @@ def edge_spmv(s_pre: jax.Array, fmt: DeviceEdgeTiles,
               interpret: bool | None = None) -> jax.Array:
     """t_i = Σ_{(j→i)} w_e s_pre_j via the edge-tile kernel. Returns f[n]."""
     interpret = default_interpret() if interpret is None else interpret
+    vals = fmt.gather_edges(fmt.pad_gather_source(s_pre))
+    if weights is not None:
+        vals = vals * weights
     out = edge_spmv_call(
-        fmt.pad_gather_source(s_pre), fmt.src_idx, fmt.dst_local,
-        fmt.block_tile, fmt.block_first, weights,
+        vals, fmt.dst_local, fmt.block_tile, fmt.block_first,
         tile=fmt.tile, e1=fmt.e1, e2=fmt.e2, num_tiles=fmt.num_tiles,
         interpret=interpret)
     return out[0, :fmt.n]
@@ -156,8 +164,8 @@ def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
     interpret = default_interpret() if interpret is None else interpret
     s_pre = jnp.pad(s, ((0, 0), (0, fmt.n_gather - fmt.n_pad))) * inv_w_gather
     s_new, gap = power_step_call(
-        s_pre, fmt.src_idx, fmt.dst_local, fmt.block_tile, fmt.block_first,
-        fmt.block_last, mu_pad, c_pad, s,
+        fmt.gather_edges(s_pre), fmt.dst_local, fmt.block_tile,
+        fmt.block_first, fmt.block_last, mu_pad, c_pad, s,
         tile=fmt.tile, e1=fmt.e1, e2=fmt.e2, num_tiles=fmt.num_tiles,
         interpret=interpret)
     return s_new, gap[0, 0]

@@ -8,6 +8,10 @@ tile's μ/c/s slices are already in VMEM, the epilogue runs there, and a
 per-kernel scalar accumulates the L1 gap — so s', and the gap cost zero
 additional HBM traffic beyond the write of s' itself.
 
+The per-edge gather ``(s ⊙ 1/w)[src]`` runs in XLA before the kernel (Mosaic
+lowers only 2-D gathers) and arrives as ``[e1, e2]`` blocks, so no
+whole-vector block has to fit in VMEM.
+
 This is the paper-faithful iteration (identical math to
 ``core.power_psi.make_power_psi_step``) — only the schedule is new
 (EXPERIMENTS.md §Perf, memory-term hillclimb).
@@ -25,9 +29,8 @@ __all__ = ["power_step_call"]
 
 
 def _make_kernel(e1: int, tile: int):
-    def kernel(block_tile_ref, first_ref, last_ref, s_pre_ref, idx_ref,
-               dstl_ref, mu_ref, c_ref, s_old_ref, out_ref, gap_ref,
-               acc_ref):
+    def kernel(block_tile_ref, first_ref, last_ref, vals_ref, dstl_ref,
+               mu_ref, c_ref, s_old_ref, out_ref, gap_ref, acc_ref):
         b = pl.program_id(0)
 
         @pl.when(b == 0)
@@ -38,53 +41,54 @@ def _make_kernel(e1: int, tile: int):
         def _zero_acc():
             acc_ref[...] = jnp.zeros_like(acc_ref)
 
-        s_vec = s_pre_ref[0]
-        idx = idx_ref[0]
-        gathered = jnp.take(s_vec, idx, axis=0)
+        vals = vals_ref[0]                                # [e1, e2]
         dstl = dstl_ref[0]
-        e2 = idx.shape[1]
+        e2 = vals.shape[1]
         acc = acc_ref[...]
         for r in range(e1):
             onehot = (dstl[r][:, None] ==
                       jax.lax.broadcasted_iota(jnp.int32, (e2, tile), 1)
-                      ).astype(s_vec.dtype)
-            acc = acc + jnp.dot(gathered[r][None, :], onehot,
-                                preferred_element_type=s_vec.dtype)
+                      ).astype(vals.dtype)
+            acc = acc + jnp.dot(vals[r][None, :], onehot,
+                                precision=jax.lax.Precision.HIGHEST,
+                                preferred_element_type=vals.dtype)
         acc_ref[...] = acc
 
         @pl.when(last_ref[b] == 1)
         def _epilogue():
             s_new = mu_ref[...] * acc_ref[...] + c_ref[...]   # [1, tile]
             out_ref[...] = s_new
-            gap_ref[0, 0] += jnp.sum(jnp.abs(s_new - s_old_ref[...]))
+            # Mosaic stores vectors only: keep the reduction [1, 1]
+            gap_ref[...] += jnp.sum(jnp.abs(s_new - s_old_ref[...]),
+                                    keepdims=True)
 
     return kernel
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "e1", "e2", "num_tiles",
                                              "interpret"))
-def power_step_call(s_pre_pad: jax.Array, src_idx: jax.Array,
-                    dst_local: jax.Array, block_tile: jax.Array,
-                    block_first: jax.Array, block_last: jax.Array,
-                    mu_pad: jax.Array, c_pad: jax.Array, s_old_pad: jax.Array,
+def power_step_call(edge_vals: jax.Array, dst_local: jax.Array,
+                    block_tile: jax.Array, block_first: jax.Array,
+                    block_last: jax.Array, mu_pad: jax.Array,
+                    c_pad: jax.Array, s_old_pad: jax.Array,
                     *, tile: int, e1: int, e2: int, num_tiles: int,
                     interpret: bool = False) -> tuple[jax.Array, jax.Array]:
     """Fused iteration over a pre-built EdgeTileFormat.
 
     Args:
-      s_pre_pad: f[1, n_gather] — s ⊙ 1/w with sentinel zeros.
+      edge_vals: f[num_blocks, e1, e2] — ``(s ⊙ 1/w)[src_idx]``, gathered
+        by XLA (sentinel slots hold 0).
       mu_pad / c_pad / s_old_pad: f[1, num_tiles*tile] node-tiled vectors.
 
     Returns:
       (s_new f[1, num_tiles*tile], gap f[1,1] = ‖s_new − s_old‖₁ over pads).
     """
-    num_blocks = src_idx.shape[0]
+    num_blocks = edge_vals.shape[0]
     vec_spec = pl.BlockSpec((1, tile), lambda b, bt, bf, bl: (0, bt[b]))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(num_blocks,),
         in_specs=[
-            pl.BlockSpec((1, s_pre_pad.shape[1]), lambda b, *_: (0, 0)),
             pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0)),
             pl.BlockSpec((1, e1, e2), lambda b, *_: (b, 0, 0)),
             vec_spec,                                   # mu
@@ -95,15 +99,15 @@ def power_step_call(s_pre_pad: jax.Array, src_idx: jax.Array,
             vec_spec,                                   # s_new
             pl.BlockSpec((1, 1), lambda b, *_: (0, 0)),  # gap scalar
         ],
-        scratch_shapes=[pltpu.VMEM((1, tile), s_pre_pad.dtype)],
+        scratch_shapes=[pltpu.VMEM((1, tile), edge_vals.dtype)],
     )
     return pl.pallas_call(
         _make_kernel(e1, tile),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((1, num_tiles * tile), s_pre_pad.dtype),
-            jax.ShapeDtypeStruct((1, 1), s_pre_pad.dtype),
+            jax.ShapeDtypeStruct((1, num_tiles * tile), edge_vals.dtype),
+            jax.ShapeDtypeStruct((1, 1), edge_vals.dtype),
         ],
         interpret=interpret,
-    )(block_tile, block_first, block_last, s_pre_pad, src_idx, dst_local,
+    )(block_tile, block_first, block_last, edge_vals, dst_local,
       mu_pad, c_pad, s_old_pad)

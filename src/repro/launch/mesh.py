@@ -1,6 +1,6 @@
-"""Production mesh construction.
+"""Mesh construction — the one place the code base calls ``jax.make_mesh``.
 
-A function (not a module-level constant) so importing this module never
+Functions (not module-level constants) so importing this module never
 touches jax device state — required because the dry-run must set
 ``--xla_force_host_platform_device_count=512`` before first jax init.
 """
@@ -8,14 +8,26 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["make_production_mesh", "HW"]
+__all__ = ["make_mesh", "make_production_mesh", "HW"]
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """A mesh whose axes are all ``Auto``.
+
+    ``jax.make_mesh`` makes Explicit axes by default; the sharded code here
+    is written for Auto axes (``with_sharding_constraint`` over mesh axes,
+    ``.at[...].set`` on sharded arrays), which Explicit axes reject.
+    """
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; multi-pod adds a leading pod axis (2×)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class HW:

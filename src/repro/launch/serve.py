@@ -314,7 +314,8 @@ def _serve_driver(args) -> None:
     else:
         from ..core.distributed import DistributedPsi
         from ..runtime import PsiDriver
-        mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        from .mesh import make_mesh
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
         drv = PsiDriver(DistributedPsi.from_graph(g, act, mesh),
                         chunk_iters=16)
         rep = drv.run(tol=tol)
@@ -576,6 +577,8 @@ def main() -> None:
                          "(per-regime correction factors) to this JSON "
                          "path at exit")
     args = ap.parse_args()
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.explain_out:
         args.explain = True
 
@@ -619,9 +622,10 @@ def main() -> None:
     import jax
     import jax.numpy as jnp
     from ..configs import get_arch
+    from .mesh import make_mesh
 
     entry = get_arch(args.arch)
-    mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
 
     if entry.family == "psi" and (args.chaos or args.stream):
         # --stream X --chaos is the combined drill: streaming ingestion

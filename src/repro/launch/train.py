@@ -37,10 +37,11 @@ def main() -> None:
     from ..ckpt import checkpoint
     from ..data import TokenPipeline
     from ..train import adamw, adafactor, cosine_schedule
+    from .mesh import make_mesh
 
     entry = get_arch(args.arch)
     n_dev = len(jax.devices())
-    mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+    mesh = make_mesh((n_dev, 1), ("data", "model"))
 
     if entry.family == "lm":
         from ..models.transformer import (init_params, make_train_step,

@@ -22,8 +22,6 @@ import dataclasses
 
 import jax
 import jax.numpy as jnp
-
-from ...compat import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -103,14 +101,14 @@ def make_sage_layer(part: Partition2D, mesh: Mesh):
         # reassemble this row's block-cyclic shard for the next layer
         return jax.lax.all_gather(h, col_axis, axis=0, tiled=True)[None]
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(src_axes, None, None),
                   ShardedGraph(src_local=P(src_axes, col_axis, None),
                                dst_local=P(src_axes, col_axis, None),
                                deg_piece=P(src_axes, col_axis, None)),
                   P(None, None), P(None), P(None, None), P(None)),
-        out_specs=P(src_axes, None, None))
+        out_specs=P(src_axes, None, None), check_vma=False)
 
 
 def sharded_sage_apply(params, x_src_layout, part: Partition2D, sg,

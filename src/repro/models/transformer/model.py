@@ -26,8 +26,6 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
-
-from ...compat import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from .attention import attention
@@ -249,11 +247,11 @@ def _moe_ffn(x, lp, cfg: LMConfig, mesh):
         out = jax.lax.psum(out, TP)
         return out.reshape(x_loc.shape)
 
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None), P(None, None), P(None, None, TP),
                   P(None, TP, None), P(None, None, TP)),
-        out_specs=P(dp, None, None)
+        out_specs=P(dp, None, None), check_vma=False,
     )(x, lp["router"], lp["w1"], lp["w2"],
       lp["w3"] if swiglu else lp["w1"])
 
@@ -303,10 +301,10 @@ def _embed_lookup(embed, tokens, cfg: LMConfig, mesh, dp):
     tok_spec = P(dp, None) if tokens.ndim == 2 else P(dp)
     out_spec = P(dp, *([None] * tokens.ndim))
     embed_dim_spec = None if not cfg.fsdp else dp_axes(mesh)
-    return shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(TP, embed_dim_spec), tok_spec),
-        out_specs=out_spec)(embed, tokens)
+        out_specs=out_spec, check_vma=False)(embed, tokens)
 
 
 # --------------------------------------------------------------------- #

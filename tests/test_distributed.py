@@ -13,6 +13,8 @@ def _run(script: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
     env["PYTHONPATH"] = _SRC
+    # every script builds its meshes through the project's Auto-axis helper
+    script = "from repro.launch.mesh import make_mesh\n" + script
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     assert out.returncode == 0, out.stderr[-4000:]
@@ -30,7 +32,7 @@ act = heterogeneous(g.n, seed=9)
 ref = power_psi(build_operators(g, act), tol=1e-10)
 for shape, axes in [((2, 4), ("data", "model")),
                     ((2, 2, 2), ("pod", "data", "model"))]:
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     dp = DistributedPsi.from_graph(g, act, mesh)
     psi, iters, gap = dp.run_to_convergence(tol=1e-7, chunk_iters=8)
     err = np.abs(psi - np.asarray(ref.psi)).max()
@@ -49,7 +51,7 @@ from repro.runtime import PsiDriver
 g = erdos_renyi(500, 3500, seed=5)
 act = heterogeneous(g.n, seed=6)
 ref = power_psi(build_operators(g, act), tol=1e-10)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 dist = DistributedPsi.from_graph(g, act, mesh)
 with tempfile.TemporaryDirectory() as d:
     drv = PsiDriver(dist, ckpt_dir=d, chunk_iters=8)
@@ -70,12 +72,12 @@ from repro.runtime import PsiDriver
 g = erdos_renyi(640, 5000, seed=7)
 act = heterogeneous(g.n, seed=8)
 ref = power_psi(build_operators(g, act), tol=1e-10)
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = make_mesh((2, 4), ("data", "model"))
 dist1 = DistributedPsi.from_graph(g, act, mesh1)
 run1 = dist1.make_run(chunk_iters=8)
 s1, _ = run1(dist1.arrays.c_src, dist1.arrays)
 drv2 = PsiDriver(dist1, chunk_iters=8).remesh(
-    jax.make_mesh((4, 2), ("data", "model")), g, act, s1)
+    make_mesh((4, 2), ("data", "model")), g, act, s1)
 dist2 = drv2.dist
 run2 = dist2.make_run(chunk_iters=8)
 s, gap = drv2._warm_s, np.inf
@@ -102,14 +104,14 @@ from repro.core.distributed import DistributedPsi
 from repro.runtime import PsiDriver
 g = erdos_renyi(640, 5000, seed=7)
 act = heterogeneous(g.n, seed=8)
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = make_mesh((2, 4), ("data", "model"))
 dist1 = DistributedPsi.from_graph(g, act, mesh1)
 # progress the contraction a few chunks on the old mesh
 run1 = dist1.make_run(chunk_iters=8)
 s1 = dist1.arrays.c_src
 for _ in range(3):
     s1, _ = run1(s1, dist1.arrays)
-mesh2 = jax.make_mesh((4, 2), ("data", "model"))
+mesh2 = make_mesh((4, 2), ("data", "model"))
 warm_drv = PsiDriver(dist1, chunk_iters=8).remesh(mesh2, g, act, s1)
 warm = warm_drv.run(tol=1e-7)
 cold = PsiDriver(warm_drv.dist, chunk_iters=8).run(tol=1e-7)
@@ -131,7 +133,7 @@ from repro.core.distributed import DistributedPsi
 g = erdos_renyi(600, 4500, seed=4)
 act = heterogeneous(g.n, seed=9)
 ref = power_psi(build_operators(g, act), tol=1e-10)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 dp = DistributedPsi.from_graph(g, act, mesh)
 step = jax.jit(dp.make_step())
 dispatch = jax.jit(dp.make_dispatch())
@@ -158,7 +160,7 @@ def test_sharded_embedding_lookup_and_grads():
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.models.recsys.embedding import sharded_lookup
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 tbl = jnp.asarray(np.random.default_rng(0).normal(size=(64, 8))
                   .astype(np.float32))
 tbl_s = jax.device_put(tbl, NamedSharding(mesh, P("model", None)))
@@ -182,7 +184,7 @@ from repro.configs import get_arch
 from repro.models.transformer import init_params, make_train_step, param_specs
 from repro.train import adamw, constant_schedule
 import dataclasses
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 for arch in ("tinyllama-1.1b", "mixtral-8x7b"):
     cfg = get_arch(arch).config(reduced=True)
     # reduced dims divisible by the 4-way model axis already (multiples of 8)
@@ -215,7 +217,7 @@ from repro.core import heterogeneous, build_operators, power_psi
 from repro.core.distributed import DistributedPsi1D
 g = erdos_renyi(500, 3600, seed=12)
 act = heterogeneous(g.n, seed=13)
-mesh = jax.make_mesh((8,), ("all",))
+mesh = make_mesh((8,), ("all",))
 d1 = DistributedPsi1D(g, act, mesh)
 step = jax.jit(d1.make_step())
 a = d1.arrays
@@ -247,7 +249,7 @@ x = rng.normal(size=(g.n, 16)).astype(np.float32)
 params = sage.init_params(cfg, jax.random.PRNGKey(0))
 ref = np.asarray(sage.apply(
     params, batch_from_graph(g, x, labels=rng.integers(0, 5, g.n)), cfg))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 part, sg = build_sharded_graph(g, mesh, bidirectional=True)
 x_shard = jax.device_put(
     np.stack([part.to_src_layout(x[:, j]) for j in range(16)], -1),

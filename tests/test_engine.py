@@ -449,8 +449,8 @@ def _mesh_1x1():
     """Pin a 1×1 mesh: partition shapes must not depend on how many host
     devices an earlier test (launch/dryrun) forced into the process."""
     import jax
-    return jax.make_mesh((1, 1), ("data", "model"),
-                         devices=jax.devices()[:1])
+    from repro.launch.mesh import make_mesh
+    return make_mesh((1, 1), ("data", "model"), devices=jax.devices()[:1])
 
 
 def test_distributed_patch_edges_block_local(platform, monkeypatch):

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import (LMConfig, MoECfg, init_params, forward,
                                       make_train_step, make_prefill,
                                       make_decode_step, init_cache,
@@ -18,7 +19,7 @@ LM_ARCHS = ["tinyllama-1.1b", "yi-9b", "nemotron-4-340b", "mixtral-8x22b",
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 @pytest.mark.parametrize("arch", LM_ARCHS)
@@ -102,7 +103,7 @@ def test_banded_swa_equals_dense_window():
 
 def test_moe_capacity_drops_tokens():
     """cap < load ⇒ overflow tokens are dropped (GShard semantics)."""
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = LMConfig(name="m", n_layers=1, d_model=32, n_heads=2, n_kv_heads=1,
                    d_ff=64, vocab=64, moe=MoECfg(2, 2, capacity_factor=0.1),
                    dtype=jnp.float32, param_dtype=jnp.float32)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.configs import get_arch
+from repro.launch.mesh import make_mesh
 from repro.models.recsys import mind
 from repro.models.recsys.embedding import embedding_bag
 from repro.train import adamw, constant_schedule
@@ -12,7 +13,7 @@ from repro.train import adamw, constant_schedule
 
 @pytest.fixture(scope="module")
 def setup():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = get_arch("mind").config(reduced=True)
     params = mind.init_params(cfg, jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
