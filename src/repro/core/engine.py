@@ -143,29 +143,33 @@ def _instrument_run(run):
     Applied automatically by :meth:`PsiEngine.__init_subclass__` to every
     backend that defines its own ``run`` — one instrumentation point for
     all current and future backends, including out-of-package ones like
-    ``repro.localpush``. When every obs sink is null the wrapper is one
-    boolean check and a tail call; otherwise it opens an ``engine.run``
-    span + a convergence record around the resolve. Instrumentation only
-    *reads* the result (and syncs it, which the drivers did anyway), so
-    the returned ψ/s are bitwise identical either way.
+    ``repro.localpush``. Every resolve runs inside an ``engine.run`` span,
+    so a profiler trace always shows it. When every obs sink is null that
+    span is all the wrapper adds; otherwise it also keeps a convergence
+    record and waits for the result inside an ``engine.sync`` span.
+    Instrumentation only *reads* the result (and syncs it, which the
+    drivers did anyway), so the returned ψ/s are bitwise identical either
+    way.
     """
 
     @functools.wraps(run)
     def wrapped(self, *args, **kwargs):
         tracker = obs_convergence.get_tracker()
         tracer = obs_trace.get_tracer()
-        if not (tracker.enabled or tracer.enabled or obs_metrics.enabled()):
-            return run(self, *args, **kwargs)
-        rec = tracker.begin(self.name,
-                            tenant=getattr(self, "obs_tenant", None))
+        live = tracker.enabled or tracer.enabled or obs_metrics.enabled()
         with obs_trace.span("engine.run", backend=self.name) as sp:
+            if not live:
+                return run(self, *args, **kwargs)
+            rec = tracker.begin(self.name,
+                                tenant=getattr(self, "obs_tenant", None))
             try:
                 res = run(self, *args, **kwargs)
             except BaseException:
                 tracker.finish(rec, converged=False,
                                duration_s=sp.duration_s)
                 raise
-            sp.sync(res.s)
+            with obs_trace.span("engine.sync"):
+                sp.sync(res.s)
         tracker.finish(rec, iterations=int(res.iterations),
                        gap=float(res.gap), converged=bool(res.converged),
                        duration_s=sp.duration_s,
@@ -848,11 +852,14 @@ class PallasEngine(PsiEngine):
     def run(self, *, tol=None, max_iter=None, s0=None) -> PsiResult:
         tol, max_iter = self.criterion.resolve(tol, max_iter)
         s_init = self._to_native(self._s0_node_order(s0))
-        s, gap, t = self._loop(self._step_args(), s_init, self._scale(),
-                               jnp.asarray(tol, self.ops.dtype),
-                               jnp.asarray(max_iter, jnp.int32))
-        s_n = self._from_native(s)
-        return self._result(self.ops.psi_epilogue(s_n), s_n, gap, t, tol)
+        with obs_trace.span("engine.loop"):
+            s, gap, t = self._loop(self._step_args(), s_init, self._scale(),
+                                   jnp.asarray(tol, self.ops.dtype),
+                                   jnp.asarray(max_iter, jnp.int32))
+        with obs_trace.span("engine.epilogue"):
+            s_n = self._from_native(s)
+            psi = self.ops.psi_epilogue(s_n)
+        return self._result(psi, s_n, gap, t, tol)
 
     # -- delta rebuilds ------------------------------------------------- #
     def patch_activity(self, users, lam=None, mu=None) -> bool:

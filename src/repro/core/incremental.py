@@ -297,12 +297,14 @@ class PsiService(RankedQueries):
         # rejected patch must leave the service serving its current fixed
         # point (HostOperators.patch_activity re-checks as a second wall)
         _validate_rates(lam, mu)
-        if not self._engine.patch_activity(users, lam=lam, mu=mu):
-            self._full_rebuild(activity=self._patched_activity(users, lam, mu))
-        self._pending = True
-        self._dirty += int(users.size)
-        if resolve:
-            self._resolve()
+        with obs_trace.span("service.update_activity", users=int(users.size)):
+            if not self._engine.patch_activity(users, lam=lam, mu=mu):
+                self._full_rebuild(
+                    activity=self._patched_activity(users, lam, mu))
+            self._pending = True
+            self._dirty += int(users.size)
+            if resolve:
+                self._resolve()
 
     def add_edges(self, src: np.ndarray, dst: np.ndarray, *,
                   resolve: bool = True) -> None:
@@ -310,17 +312,18 @@ class PsiService(RankedQueries):
         dst = np.asarray(dst, np.int32).reshape(-1)
         if src.size == 0:
             return
-        if not self._engine.patch_edges(src, dst):
-            g = self._engine.graph
-            merged = Graph(
-                g.n, np.concatenate([g.src, src]),
-                np.concatenate([g.dst, dst]),
-                name=g.name).dedup()
-            self._full_rebuild(graph=merged)
-        self._pending = True
-        self._dirty += int(src.size)
-        if resolve:
-            self._resolve()
+        with obs_trace.span("service.add_edges", edges=int(src.size)):
+            if not self._engine.patch_edges(src, dst):
+                g = self._engine.graph
+                merged = Graph(
+                    g.n, np.concatenate([g.src, src]),
+                    np.concatenate([g.dst, dst]),
+                    name=g.name).dedup()
+                self._full_rebuild(graph=merged)
+            self._pending = True
+            self._dirty += int(src.size)
+            if resolve:
+                self._resolve()
 
     def remove_edges(self, src: np.ndarray, dst: np.ndarray, *,
                      resolve: bool = True) -> None:
@@ -331,16 +334,17 @@ class PsiService(RankedQueries):
         dst = np.asarray(dst, np.int32).reshape(-1)
         if src.size == 0:
             return
-        if not self._engine.unpatch_edges(src, dst):
-            g = self._engine.graph
-            keep = ~np.isin(g.src.astype(np.int64) * g.n + g.dst,
-                            src.astype(np.int64) * g.n + dst)
-            self._full_rebuild(graph=Graph(g.n, g.src[keep], g.dst[keep],
-                                           name=g.name))
-        self._pending = True
-        self._dirty += int(src.size)
-        if resolve:
-            self._resolve()
+        with obs_trace.span("service.remove_edges", edges=int(src.size)):
+            if not self._engine.unpatch_edges(src, dst):
+                g = self._engine.graph
+                keep = ~np.isin(g.src.astype(np.int64) * g.n + g.dst,
+                                src.astype(np.int64) * g.n + dst)
+                self._full_rebuild(graph=Graph(
+                    g.n, g.src[keep], g.dst[keep], name=g.name))
+            self._pending = True
+            self._dirty += int(src.size)
+            if resolve:
+                self._resolve()
 
     @property
     def stale(self) -> bool:
@@ -426,8 +430,9 @@ class PsiService(RankedQueries):
     def _resolve(self) -> None:
         self._plan_query(None)                    # log the solver verdict
         prev_s = None if self._last is None else self._last.s
-        self._last = self._engine.run(tol=self.tol, max_iter=self.max_iter,
-                                      s0=prev_s)
+        with obs_trace.span("service.resolve"):
+            self._last = self._engine.run(tol=self.tol,
+                                          max_iter=self.max_iter, s0=prev_s)
         self._cache = None                        # ranking invalidated
         self._pending = False
         self._dirty = 0

@@ -74,8 +74,8 @@ class PsiOperators:
         return self.mu * self.push(s)
 
     def psi_epilogue(self, s: jax.Array) -> jax.Array:
-        """ψᵀ = (sᵀB + dᵀ)/N  (Eq. 12 epilogue)."""
-        return (self.lam * self.push(s) + self.d) / self.n
+        """ψᵀ = (sᵀB + dᵀ)/N  (Eq. 12 epilogue), as one jitted program."""
+        return _psi_epilogue(self, s)
 
     def right_matvec(self, p: jax.Array) -> jax.Array:
         """A p — used by the Power-NF baseline. Supports batched p [N, K]."""
@@ -101,6 +101,14 @@ jax.tree_util.register_dataclass(
                  "lam", "mu", "inv_w", "c", "d", "b_norm"],
     meta_fields=["n", "m"],
 )
+
+
+@jax.jit
+def _psi_epilogue(ops: PsiOperators, s: jax.Array) -> jax.Array:
+    # jitted so that the scope reaches its ops: an op dispatched eagerly
+    # compiles as a program of its own, with no enclosing scope in its name
+    with jax.named_scope("psi.epilogue"):
+        return (ops.lam * ops.push(s) + ops.d) / ops.n
 
 
 def _induced_l1T_norm(n, src, dst, lam, inv_w) -> np.ndarray:

@@ -67,4 +67,5 @@ def bsr_spmv_call(s_pre_pad: jax.Array, tiles: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, num_dst_tiles * td),
                                        s_pre_pad.dtype),
         interpret=interpret,
+        name="psi_bsr_spmv",
     )(src_tile, dst_tile, block_first, s_pre_pad, tiles)

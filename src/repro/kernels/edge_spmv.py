@@ -85,4 +85,5 @@ def edge_spmv_call(edge_vals: jax.Array, dst_local: jax.Array,
         out_shape=jax.ShapeDtypeStruct((1, num_tiles * tile),
                                        edge_vals.dtype),
         interpret=interpret,
+        name="psi_edge_spmv",
     )(block_tile, block_first, edge_vals, dst_local)

@@ -162,9 +162,12 @@ def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
       (s_new f[1, n_pad], gap scalar ‖Δs‖₁).
     """
     interpret = default_interpret() if interpret is None else interpret
-    s_pre = jnp.pad(s, ((0, 0), (0, fmt.n_gather - fmt.n_pad))) * inv_w_gather
+    with jax.named_scope("psi.gather"):
+        s_pre = jnp.pad(s, ((0, 0), (0, fmt.n_gather - fmt.n_pad))) \
+            * inv_w_gather
+        vals = fmt.gather_edges(s_pre)
     s_new, gap = power_step_call(
-        fmt.gather_edges(s_pre), fmt.dst_local, fmt.block_tile,
+        vals, fmt.dst_local, fmt.block_tile,
         fmt.block_first, fmt.block_last, mu_pad, c_pad, s,
         tile=fmt.tile, e1=fmt.e1, e2=fmt.e2, num_tiles=fmt.num_tiles,
         interpret=interpret)

@@ -109,5 +109,6 @@ def power_step_call(edge_vals: jax.Array, dst_local: jax.Array,
             jax.ShapeDtypeStruct((1, 1), edge_vals.dtype),
         ],
         interpret=interpret,
+        name="psi_power_step",
     )(block_tile, block_first, block_last, edge_vals, dst_local,
       mu_pad, c_pad, s_old_pad)

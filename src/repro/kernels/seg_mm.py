@@ -70,6 +70,7 @@ def seg_mm_call(messages: jax.Array, dst_local: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_tiles, tile, d), messages.dtype),
         interpret=interpret,
+        name="psi_seg_mm",
     )(block_tile, block_first, messages, dst_local)
     return out.reshape(num_tiles * tile, d)
 

@@ -16,8 +16,6 @@ The analysis-and-control layer on top of the measurements:
   stacks, per-backend cost attribution, async critical-path extraction.
 - :mod:`repro.obs.watch` — online convergence anomaly detection feeding
   pre-emptive advice into the resilience ladder.
-- :mod:`repro.obs.regress` — noise-aware perf-regression gate over the
-  benchmark trajectory (``python -m repro.obs.regress``).
 
 Instrumentation sites throughout the stack call the cheap module-level
 helpers (``metrics.counter(...)``, ``trace.span(...)``,
@@ -47,7 +45,7 @@ from .watch import ConvergenceWatch
 
 __all__ = [
     "metrics", "trace", "convergence", "log",
-    "slo", "profile", "watch", "regress", "explain", "calibrate",
+    "slo", "profile", "watch", "explain", "calibrate",
     "MetricsRegistry", "NullRegistry", "Tracer", "Span",
     "ConvergenceTracker", "span", "retrace_guard",
     "SLO", "SLOEngine", "default_slos", "Profile", "ConvergenceWatch",
@@ -55,15 +53,6 @@ __all__ = [
     "environment_fingerprint", "start_http_server",
     "configure", "disable", "enabled", "dump",
 ]
-
-
-def __getattr__(name):
-    # lazy: regress is a CLI module; importing it eagerly would trip the
-    # runpy double-import warning under `python -m repro.obs.regress`
-    if name == "regress":
-        from . import regress
-        return regress
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 
 def enabled() -> bool:

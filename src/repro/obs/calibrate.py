@@ -48,9 +48,8 @@ _REPUBLISH_REL = 0.10
 def env_key(fingerprint: dict | None = None) -> str:
     """Reduced environment key: platform / device kind / x64 flag.
 
-    Follows the :mod:`repro.obs.regress` matching convention — correction
-    factors are per-machine-class facts, so the volatile fingerprint
-    fields (timestamp, git sha) stay out of the key.
+    Correction factors are per-machine-class facts, so the volatile
+    fingerprint fields (timestamp, git sha) stay out of the key.
     """
     if fingerprint is None:
         from .env import environment_fingerprint

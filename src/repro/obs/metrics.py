@@ -190,13 +190,19 @@ class _HistogramChild:
 
     def quantile(self, q: float) -> float:
         """Estimated q-quantile: linear interpolation inside the bucket the
-        quantile falls in (exact min/max tighten the edge buckets)."""
+        quantile falls in (exact min/max tighten the edge buckets); q = 0
+        and q = 1 are the exact min and max, since interpolating can round
+        off either."""
         if not 0.0 <= q <= 1.0:
             raise ValueError(f"quantile must be in [0, 1]; got {q}")
         with self._lock:
             total = self.count
             if total == 0:
                 return 0.0
+            if q == 0.0:
+                return self._min
+            if q == 1.0:
+                return self._max
             target = q * total
             cum = 0
             for i, c in enumerate(self.counts):

@@ -7,6 +7,15 @@ reads), and *emits* only when a live :class:`Tracer` is installed, so
 ``DriverReport.chunk_durations`` and the trace file can never disagree
 about the same chunk: they are the same measurement.
 
+Every :class:`Span` also holds a ``jax.profiler.TraceAnnotation`` of its
+name open, tracer or not: while a JAX profiler trace is active the span
+lands on the trace's host plane, on the profiler's clock, beside the
+device ops it dispatched, marked with the stat ``origin=repro.obs``
+(:data:`PROFILER_MARK`) so a reader of the trace can tell the program's
+spans from every other host event. With no profiler active the annotation
+costs under a microsecond and records nothing. JAX is imported on the
+first span, so the module itself imports without it.
+
 JAX-aware timing: device work is dispatched asynchronously, so the wall
 around a jitted call conflates host dispatch with device compute. Calling
 :meth:`Span.sync` on the result splits them — host time up to the sync
@@ -28,6 +37,8 @@ recompiles* (the jit cache growing past its first entry — e.g. the known
 """
 from __future__ import annotations
 
+import contextlib
+import functools
 import itertools
 import json
 import os
@@ -44,8 +55,21 @@ __all__ = ["now", "Span", "Tracer", "NULL_TRACER", "get_tracer",
 #: in the repo is a difference of two now() reads
 now = time.perf_counter
 
+#: the stat every span's profiler annotation carries
+PROFILER_MARK = {"origin": "repro.obs"}
+
 _TLS = threading.local()
 _IDS = itertools.count(1)
+
+
+@functools.cache
+def _annotation_type():
+    """``jax.profiler.TraceAnnotation``, or a no-op where JAX is absent."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:                            # pragma: no cover
+        return lambda name, **stat: contextlib.nullcontext()
+    return TraceAnnotation
 
 
 def _stack() -> list:
@@ -66,7 +90,8 @@ class Span:
     """
 
     __slots__ = ("name", "attrs", "tracer", "t0", "t1", "dispatch_s",
-                 "sync_s", "span_id", "parent_id", "depth", "thread")
+                 "sync_s", "span_id", "parent_id", "depth", "thread",
+                 "_annotation")
 
     def __init__(self, name: str, tracer, attrs: dict):
         self.name = name
@@ -79,6 +104,7 @@ class Span:
         self.parent_id = None
         self.depth = 0
         self.thread = threading.current_thread().name
+        self._annotation = _annotation_type()(name, **PROFILER_MARK)
 
     def __enter__(self) -> "Span":
         st = _stack()
@@ -86,11 +112,13 @@ class Span:
             self.parent_id = st[-1].span_id
             self.depth = len(st)
         st.append(self)
+        self._annotation.__enter__()
         self.t0 = now()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.t1 = now()
+        self._annotation.__exit__(exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()

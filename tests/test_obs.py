@@ -243,7 +243,8 @@ def test_checkpoint_corruption_routes_through_obs(fresh_obs):
 # --------------------------------------------------------------------- #
 # parity: instrumentation only ever reads
 # --------------------------------------------------------------------- #
-def test_instrumented_psi_bitwise_parity():
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_instrumented_psi_bitwise_parity(backend):
     from repro.core import heterogeneous, make_engine
     from repro.graphs import powerlaw_configuration
 
@@ -252,7 +253,7 @@ def test_instrumented_psi_bitwise_parity():
 
     def solve():
         return np.array(
-            make_engine("reference", graph=g, activity=act).run(tol=1e-8).psi,
+            make_engine(backend, graph=g, activity=act).run(tol=1e-8).psi,
             copy=True)
 
     prev = obs.configure(registry=MetricsRegistry(),
@@ -261,7 +262,7 @@ def test_instrumented_psi_bitwise_parity():
     try:
         live = solve()
         assert obs_metrics.get_registry().value(
-            "psi_resolves_total", backend="reference") == 1.0
+            "psi_resolves_total", backend=backend) == 1.0
     finally:
         obs.restore(prev)
     prev = obs.disable()
@@ -290,3 +291,86 @@ def test_query_metrics_and_cache_hit_ratio(fresh_obs):
     pooled = reg.get("psi_query_seconds").merged()
     assert pooled.count == hits + misses
     assert pooled.quantile(0.5) <= pooled.quantile(0.99)
+
+
+# --------------------------------------------------------------------- #
+# the solve's layers, as a profiler sees them
+# --------------------------------------------------------------------- #
+def _host_spans(trace_dir) -> dict:
+    """``{name: (start_ns, end_ns)}`` of the program's spans (the host
+    events marked ``PROFILER_MARK``) on the host plane of the trace under
+    ``trace_dir``."""
+    import glob
+
+    import jax
+    path, = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    mark = set(obs.trace.PROFILER_MARK.items())
+    return {e.name: (e.start_ns, e.start_ns + e.duration_ns)
+            for plane in jax.profiler.ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if mark <= set(e.stats)}
+
+
+@pytest.mark.parametrize("plane", ["dark", "live"])
+def test_spans_land_on_the_profiler_host_plane(tmp_path, plane):
+    """Every Span is also a profiler annotation: a trace of one pallas
+    solve shows ``engine.loop`` and then ``engine.epilogue`` inside
+    ``engine.run`` (and, with the obs plane live, its ``engine.sync``)."""
+    import jax
+
+    from repro.core import PsiService, heterogeneous
+    from repro.graphs import powerlaw_configuration
+
+    g = powerlaw_configuration(300, 1_800, seed=3)
+    svc = PsiService(g, heterogeneous(g.n, seed=4), tol=1e-6,
+                     backend="pallas")
+    svc.scores()                                 # compile outside the trace
+    prev = (obs.disable() if plane == "dark" else
+            obs.configure(registry=MetricsRegistry(),
+                          tracer=obs.Tracer(None),
+                          tracker=obs.ConvergenceTracker()))
+    try:
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            svc.update_activity(np.arange(5), lam=np.full(5, 0.5))
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        obs.restore(prev)
+    spans = _host_spans(str(tmp_path))
+    inner = ["engine.loop", "engine.epilogue"] + \
+        (["engine.sync"] if plane == "live" else [])
+    assert set(spans) == {"service.update_activity", "service.resolve",
+                          "engine.run", *inner}
+
+    def inside(name, outer):
+        lo, hi = spans[outer]
+        return lo <= spans[name][0] <= spans[name][1] <= hi
+
+    assert inside("service.resolve", "service.update_activity")
+    assert inside("engine.run", "service.resolve")
+    assert all(inside(name, "engine.run") for name in inner)
+    assert spans["engine.loop"][1] <= spans["engine.epilogue"][0]
+
+
+def test_solve_layers_are_named_in_the_compiled_programs():
+    """The XLA gather carries the ``psi.gather`` scope and the kernel the
+    name ``psi_power_step`` in the loop's op metadata; the epilogue's ops
+    carry ``psi.epilogue``. A profiler trace finds the layers by these."""
+    import jax.numpy as jnp
+
+    from repro.core import heterogeneous, make_engine
+    from repro.core.operators import _psi_epilogue
+    from repro.graphs import powerlaw_configuration
+
+    g = powerlaw_configuration(300, 1_800, seed=3)
+    eng = make_engine("pallas", graph=g, activity=heterogeneous(g.n, seed=4))
+    loop = eng._loop.lower(eng._step_args(), eng._to_native(eng.ops.c),
+                           eng._scale(), jnp.float32(1e-6),
+                           jnp.int32(5)).compile().as_text()
+    assert 'psi.gather/gather"' in loop
+    assert "/psi_power_step/" in loop
+    epilogue = _psi_epilogue.lower(eng.ops, eng.ops.c).compile().as_text()
+    assert 'op_name="jit(_psi_epilogue)/psi.epilogue/' in epilogue

@@ -1,6 +1,6 @@
 """Units for the obs analysis-and-control layer (PR 9): SLO engine +
-burn-rate alerting, span-stream profiler, convergence watch +
-pre-emptive supervision, and the noise-aware perf-regression gate."""
+burn-rate alerting, span-stream profiler, and convergence watch +
+pre-emptive supervision."""
 import json
 import urllib.error
 import urllib.request
@@ -14,8 +14,6 @@ from repro.obs import metrics as obs_metrics
 from repro.obs.convergence import ResolveRecord
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.profile import Profile
-from repro.obs.regress import gate, inject_slowdown
-from repro.obs.regress import main as regress_main
 from repro.obs.slo import (BurnRule, SLO, SLOEngine, counter_ratio,
                            default_slos, gauge_value, histogram_quantile)
 from repro.obs.watch import ConvergenceWatch
@@ -393,101 +391,3 @@ def test_watch_feeds_preemptive_rechunk_into_the_ladder(fresh_obs):
     # advice was consumed: a second resolve does not re-preempt
     res.resolve()
     assert res.report.preemptions == ["rechunk"]
-
-
-# --------------------------------------------------------------------- #
-# perf-regression gate
-# --------------------------------------------------------------------- #
-def _bench_doc(cand_wall=1.0, *, n_base=4, env=None, cand_env=None,
-               quick=False, cand_quick=None):
-    def run(label, wall, environment, q):
-        return dict(label=label, quick=q, environment=environment,
-                    entries=[dict(graph="powerlaw", backend="reference",
-                                  regime=None, n=100, m=500,
-                                  dtype="float64", tol=1e-8,
-                                  wall_s=wall, matvecs=40,
-                                  work_frac=0.5)])
-    runs = [run(f"b{i}", 1.0 + 0.01 * i, env or {}, quick)
-            for i in range(n_base)]
-    runs.append(run("cand", cand_wall,
-                    cand_env if cand_env is not None else (env or {}),
-                    quick if cand_quick is None else cand_quick))
-    return dict(schema=1, runs=runs)
-
-
-def test_gate_passes_within_noise_and_catches_slowdown():
-    assert gate(_bench_doc(1.02))["ok"]
-    verdict = gate(_bench_doc(2.1))
-    assert not verdict["ok"]
-    assert any("powerlaw/reference" in r and "wall_s" in r
-               for r in verdict["regressions"])
-    row = next(r for r in verdict["rows"]
-               if r["metric"] == "wall_s")
-    assert row["status"] == "regression" and row["baselines"] == 4
-
-
-def test_gate_mad_absorbs_one_noisy_baseline():
-    doc = _bench_doc(1.05)
-    doc["runs"][0]["entries"][0]["wall_s"] = 30.0   # one wild outlier
-    verdict = gate(doc)
-    assert verdict["ok"], "median/MAD must shrug off a single outlier"
-
-
-def test_gate_direction_higher_is_better():
-    doc = _bench_doc()
-    for r in doc["runs"]:
-        r["entries"][0]["events_per_s"] = (
-            5000.0 if r["label"] != "cand" else 2000.0)
-    verdict = gate(doc)
-    assert not verdict["ok"]
-    assert any("events_per_s" in r for r in verdict["regressions"])
-
-
-def test_gate_env_and_quick_matching():
-    # env mismatch -> no baselines -> skipped, not compared
-    doc = _bench_doc(9.0, env={"device_platform": "cpu"},
-                     cand_env={"device_platform": "gpu"})
-    verdict = gate(doc)
-    assert verdict["ok"] and verdict["baselines"] == []
-    assert all(r["status"] == "skipped" for r in verdict["rows"])
-    # empty env on old runs is a wildcard: still comparable
-    doc = _bench_doc(1.0, env={}, cand_env={"device_platform": "cpu"})
-    assert len(gate(doc)["baselines"]) == 4
-    # quick runs never gate against full runs
-    doc = _bench_doc(9.0, cand_quick=True)
-    assert gate(doc)["ok"] and gate(doc)["baselines"] == []
-
-
-def test_inject_slowdown_is_caught_and_original_untouched():
-    doc = _bench_doc(1.0)
-    slowed = inject_slowdown(doc, factor=2.0)
-    assert doc["runs"][-1]["entries"][0]["wall_s"] == 1.0
-    assert slowed["runs"][-1]["entries"][0]["wall_s"] == 2.0
-    assert gate(doc)["ok"] and not gate(slowed)["ok"]
-
-
-def test_regress_cli_exit_codes(tmp_path):
-    good = tmp_path / "bench.json"
-    good.write_text(json.dumps(_bench_doc(1.0)))
-    out = tmp_path / "verdict.json"
-    assert regress_main(["--json", str(good), "--out", str(out),
-                         "--self-check"]) == 0
-    verdict = json.loads(out.read_text())
-    assert verdict["ok"] and verdict["candidate"] == "cand"
-    bad = tmp_path / "bench_bad.json"
-    bad.write_text(json.dumps(_bench_doc(3.0)))
-    assert regress_main(["--json", str(bad)]) == 1
-
-
-def test_regress_gates_the_checked_in_trajectory():
-    import os
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "BENCH_power_psi.json")
-    with open(path) as f:
-        doc = json.load(f)
-    verdict = gate(doc, quick=bool(
-        doc["runs"][-1].get("quick")))
-    assert verdict["ok"], verdict["regressions"]
-    slowed = inject_slowdown(doc, factor=2.0)
-    assert not gate(slowed, quick=bool(
-        doc["runs"][-1].get("quick")))["ok"]

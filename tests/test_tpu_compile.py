@@ -81,8 +81,12 @@ def _compile(fn, *args):
     return jax.jit(fn).lower(*args).compile()
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """The kernel is compiled for the chip, with its name in its op_name:
+    a profiler trace finds it by that name."""
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"/{name}/" in text
 
 
 @pytest.mark.parametrize("tile,e1,e2,n,nb", EDGE_TILE_SHAPES)
@@ -95,7 +99,7 @@ def test_power_step_compiles(sd, tile, e1, e2, n, nb):
                                    num_tiles=num_tiles),
         sd((nb, e1, e2), F32), sd((nb, e1, e2), I32), blocks, blocks, blocks,
         vec, vec, vec)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "psi_power_step")
 
 
 @pytest.mark.parametrize("tile,e1,e2,n,nb", EDGE_TILE_SHAPES)
@@ -106,7 +110,7 @@ def test_edge_spmv_compiles(sd, tile, e1, e2, n, nb):
         lambda *a: edge_spmv_call(*a, tile=tile, e1=e1, e2=e2,
                                   num_tiles=num_tiles),
         sd((nb, e1, e2), F32), sd((nb, e1, e2), I32), blocks, blocks)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "psi_edge_spmv")
 
 
 @pytest.mark.parametrize("ts,td", BSR_CANDIDATES)
@@ -120,7 +124,7 @@ def test_bsr_spmv_compiles(sd, ts, td):
                                  num_dst_tiles=num_dst_tiles),
         sd((1, n_src_pad), F32), sd((nb, ts, td), F32), blocks, blocks,
         blocks)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "psi_bsr_spmv")
 
 
 def _device_edge_tiles(sd, lanes, tile, e1, e2, n):
@@ -145,7 +149,9 @@ def test_fleet_vmapped_edge_tile_step_compiles(sd):
     args = (fmt, sd((lanes, 1, fmt.n_gather), F32), vec, vec)
     compiled = _compile(jax.vmap(make_edge_tile_step(interpret=False)),
                         args, vec)
-    _assert_kernel(compiled)
+    _assert_kernel(compiled, "psi_power_step")
+    # the XLA gather before the kernel keeps its scope through the compile
+    assert "vmap(psi.gather)/gather" in compiled.as_text()
 
 
 def test_reference_step_compiles(sd):
