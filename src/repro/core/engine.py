@@ -535,9 +535,9 @@ def make_edge_tile_step(interpret: bool):
     Same calling convention as :func:`make_reference_step` but in the
     pallas edge-tile regime's native padded ``[1, n_pad]`` layout; the args
     tuple is ``(DeviceEdgeTiles, inv_w_gather, mu_pad, c_pad)``.  The
-    pallas call batches under ``jax.vmap`` (the batch axis becomes a grid
-    dimension), which is how the fleet runs many tenants per device
-    through one kernel launch.
+    pallas calls (the ``psi_gather`` gather and the fused step) batch
+    under ``jax.vmap`` (the batch axis becomes a grid dimension), which is
+    how the fleet runs many tenants per device through one launch.
     """
     from ..kernels.ops import power_step
 

@@ -14,9 +14,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs import metrics as obs_metrics
 from .formats import BsrFormat, EdgeTileFormat, build_bsr, build_edge_tiles
 from .edge_spmv import edge_spmv_call
 from .bsr_spmv import bsr_spmv_call
+from .gather import fits_vmem, gather_call
 from .power_step import power_step_call
 from .seg_mm import seg_mm_call
 
@@ -66,7 +68,9 @@ class DeviceEdgeTiles:
 
     def gather_edges(self, s_pre_pad: jax.Array) -> jax.Array:
         """f[1, n_gather] → f[num_blocks, e1, e2]: each slot's source value
-        (sentinel slots read the zero at index n)."""
+        (sentinel slots read the zero at index n), by an XLA gather that
+        reads each slot from HBM. :func:`power_step` gathers in VMEM
+        instead wherever the source fits (:func:`gather_step_edges`)."""
         return s_pre_pad[0][self.src_idx]
 
     def pad_node_vector(self, v: jax.Array) -> jax.Array:
@@ -149,10 +153,31 @@ def seg_mm(messages: jax.Array, fmt: DeviceEdgeTiles,
     return out[:fmt.n]
 
 
+def gather_step_edges(s_pre_pad: jax.Array, fmt: DeviceEdgeTiles,
+                      interpret: bool) -> jax.Array:
+    """Each edge slot's source value, as ``fmt.gather_edges`` gives it.
+
+    The ``psi_gather`` kernel holds the source in VMEM where it fits
+    (``fits_vmem``: a float32 source within the kernel's VMEM budget);
+    any other keeps the XLA gather. ``psi_gather_path_total{path}``
+    counts each step built on either path.
+    """
+    vmem = fits_vmem(fmt.n_gather, fmt.e2, s_pre_pad.dtype)
+    obs_metrics.counter("psi_gather_path_total",
+                        "edge-tile steps built, by gather path",
+                        labelnames=("path",)) \
+        .labels(path="vmem" if vmem else "xla").inc()
+    if vmem:
+        return gather_call(s_pre_pad, fmt.src_idx, interpret=interpret)
+    return fmt.gather_edges(s_pre_pad)
+
+
 def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
                c_pad: jax.Array, fmt: DeviceEdgeTiles,
                interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
-    """One fused Alg. 2 step on padded [1, n_pad] node vectors.
+    """One fused Alg. 2 step on padded [1, n_pad] node vectors: the edge
+    slots' sources gathered by :func:`gather_step_edges`, then the
+    ``psi_power_step`` kernel's scatter, μ/c epilogue and gap.
 
     Args:
       s: f[1, n_pad] current series vector (padded layout).
@@ -165,7 +190,7 @@ def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
     with jax.named_scope("psi.gather"):
         s_pre = jnp.pad(s, ((0, 0), (0, fmt.n_gather - fmt.n_pad))) \
             * inv_w_gather
-        vals = fmt.gather_edges(s_pre)
+        vals = gather_step_edges(s_pre, fmt, interpret)
     s_new, gap = power_step_call(
         vals, fmt.dst_local, fmt.block_tile,
         fmt.block_first, fmt.block_last, mu_pad, c_pad, s,

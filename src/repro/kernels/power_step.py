@@ -8,9 +8,11 @@ tile's μ/c/s slices are already in VMEM, the epilogue runs there, and a
 per-kernel scalar accumulates the L1 gap — so s', and the gap cost zero
 additional HBM traffic beyond the write of s' itself.
 
-The per-edge gather ``(s ⊙ 1/w)[src]`` runs in XLA before the kernel (Mosaic
-lowers only 2-D gathers) and arrives as ``[e1, e2]`` blocks, so no
-whole-vector block has to fit in VMEM.
+The per-edge gather ``(s ⊙ 1/w)[src]`` runs before this kernel and arrives
+as ``[e1, e2]`` blocks: in the ``psi_gather`` kernel (``kernels/gather.py``),
+which holds the source vector in VMEM, where it fits, and in XLA otherwise
+(``kernels/ops.py`` ``gather_step_edges``). This kernel itself keeps no
+whole-vector block in VMEM.
 
 This is the paper-faithful iteration (identical math to
 ``core.power_psi.make_power_psi_step``) — only the schedule is new
@@ -77,7 +79,7 @@ def power_step_call(edge_vals: jax.Array, dst_local: jax.Array,
 
     Args:
       edge_vals: f[num_blocks, e1, e2] — ``(s ⊙ 1/w)[src_idx]``, gathered
-        by XLA (sentinel slots hold 0).
+        before the call (sentinel slots hold 0).
       mu_pad / c_pad / s_old_pad: f[1, num_tiles*tile] node-tiled vectors.
 
     Returns:

@@ -3,6 +3,7 @@ safety under the async scheduler, null-path overhead, the retrace guard,
 and the instrumented-vs-disabled bitwise parity contract."""
 import json
 import os
+import re
 import tempfile
 import threading
 import time
@@ -356,9 +357,10 @@ def test_spans_land_on_the_profiler_host_plane(tmp_path, plane):
 
 
 def test_solve_layers_are_named_in_the_compiled_programs():
-    """The XLA gather carries the ``psi.gather`` scope and the kernel the
-    name ``psi_power_step`` in the loop's op metadata; the epilogue's ops
-    carry ``psi.epilogue``. A profiler trace finds the layers by these."""
+    """The ``psi_gather`` kernel carries the ``psi.gather`` scope and the
+    kernel the name ``psi_power_step`` in the loop's op metadata, and no
+    XLA gather is left in the loop; the epilogue's ops carry
+    ``psi.epilogue``. A profiler trace finds the layers by these."""
     import jax.numpy as jnp
 
     from repro.core import heterogeneous, make_engine
@@ -370,7 +372,8 @@ def test_solve_layers_are_named_in_the_compiled_programs():
     loop = eng._loop.lower(eng._step_args(), eng._to_native(eng.ops.c),
                            eng._scale(), jnp.float32(1e-6),
                            jnp.int32(5)).compile().as_text()
-    assert 'psi.gather/gather"' in loop
+    assert "psi.gather/jit(gather_call)/psi_gather/" in loop
+    assert not re.search(r"\sgather\(", loop)
     assert "/psi_power_step/" in loop
     epilogue = _psi_epilogue.lower(eng.ops, eng.ops.c).compile().as_text()
     assert 'op_name="jit(_psi_epilogue)/psi.epilogue/' in epilogue

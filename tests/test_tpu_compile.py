@@ -12,6 +12,7 @@ process may load the TPU library, and under pytest-xdist every worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +24,7 @@ from repro.core.operators import PsiOperators
 from repro.kernels.autotune import BSR_CANDIDATES, EDGE_TILE_CANDIDATES
 from repro.kernels.bsr_spmv import bsr_spmv_call
 from repro.kernels.edge_spmv import edge_spmv_call
+from repro.kernels.gather import fits_vmem, gather_call
 from repro.kernels.ops import DeviceEdgeTiles
 from repro.kernels.power_step import power_step_call
 
@@ -75,6 +77,8 @@ EDGE_TILE_SHAPES = [
     pytest.param(t, a, b, N, _twitter_blocks(t, a, b), id=f"twitter-{t}")
     for t, a, b in EDGE_TILE_CANDIDATES
 ] + [pytest.param(256, 8, 128, 1 << 21, 36_163, id="rmat21-256")]
+# the benchmark cell's plan: Graph500 scale 21 at tile 512, 33,706 blocks
+CELL_SHAPE = pytest.param(512, 8, 128, 1 << 21, 33_706, id="g500-s21-512")
 
 
 def _compile(fn, *args):
@@ -100,6 +104,16 @@ def test_power_step_compiles(sd, tile, e1, e2, n, nb):
         sd((nb, e1, e2), F32), sd((nb, e1, e2), I32), blocks, blocks, blocks,
         vec, vec, vec)
     _assert_kernel(compiled, "psi_power_step")
+
+
+@pytest.mark.parametrize("tile,e1,e2,n,nb", EDGE_TILE_SHAPES + [CELL_SHAPE])
+def test_gather_compiles(sd, tile, e1, e2, n, nb):
+    """The whole gather source resident in VMEM, the slot indices streamed."""
+    n_gather = -(-n // tile) * tile + 128
+    assert fits_vmem(n_gather, e2, F32)
+    compiled = _compile(gather_call, sd((1, n_gather), F32),
+                        sd((nb, e1, e2), I32))
+    _assert_kernel(compiled, "psi_gather")
 
 
 @pytest.mark.parametrize("tile,e1,e2,n,nb", EDGE_TILE_SHAPES)
@@ -150,8 +164,11 @@ def test_fleet_vmapped_edge_tile_step_compiles(sd):
     compiled = _compile(jax.vmap(make_edge_tile_step(interpret=False)),
                         args, vec)
     _assert_kernel(compiled, "psi_power_step")
-    # the XLA gather before the kernel keeps its scope through the compile
-    assert "vmap(psi.gather)/gather" in compiled.as_text()
+    # the gather kernel, batched over the lanes, keeps its scope through
+    # the compile, and no XLA gather is left in the step
+    text = compiled.as_text()
+    assert "vmap(psi.gather)/jit(gather_call)/psi_gather/" in text
+    assert not re.search(r"\sgather\(", text)
 
 
 def test_reference_step_compiles(sd):
