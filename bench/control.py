@@ -2,6 +2,7 @@
 
     python3 bench/control.py --workload g500-s21.cold --seeds 11 12 13 --seconds 1
     python3 bench/control.py --program --workload g500-s21.cold --seeds 11 12 13 --seconds 1
+    python3 bench/control.py --workload g500-s21.refresh --max-iter 100 --seeds 11 12 13 --seconds 1
 
 A cell's configuration states float32; the nearest precision below it is
 bfloat16, the step that would tempt a later change. The program's own
@@ -14,6 +15,10 @@ for each seed the numbers ``correct`` compares. A sound benchmark reads the
 control as not correct. With ``--program`` it drives the program itself
 through the same harness, seed after seed, for the lower reading of each
 compared number. The benchmark's own runs never run this.
+
+For a refresh mix the control serves as a thin ``PsiService``: it patches
+its own float64 (λ, μ), re-solves warm from its previous s, and answers
+``top_k``, ``rank_of`` and ``scores_batch`` with NumPy from the ψ it serves.
 """
 from __future__ import annotations
 
@@ -33,6 +38,7 @@ ROOT = Path(__file__).resolve().parents[1]
 class _Result:
     psi: object
     iterations: object
+    s: object = None
 
 
 class _Engine:
@@ -51,7 +57,7 @@ class _Engine:
         self._solve = jax.jit(self._program, static_argnums=(0,))
 
     @staticmethod
-    def _program(n, src, dst, lam, mu, tol, max_iter):
+    def _program(n, src, dst, lam, mu, s0, warm, tol, max_iter):
         import jax
         import jax.numpy as jnp
         zero = jnp.zeros((), lam.dtype)
@@ -74,36 +80,76 @@ class _Engine:
             s_new = mu * push(s) + c
             return s_new, b_norm * jnp.sum(jnp.abs(s_new - s)), t + 1
 
+        start = jnp.where(warm, s0, c)
         s, _, t = jax.lax.while_loop(
-            cond, body, (c, jnp.asarray(jnp.inf, lam.dtype),
+            cond, body, (start, jnp.asarray(jnp.inf, lam.dtype),
                          jnp.asarray(0, jnp.int32)))
-        return (lam * push(s) + d) / n, t
+        return (lam * push(s) + d) / n, s, t
 
-    def run(self, *, tol=None, max_iter=None) -> _Result:
-        """A cold solve from c."""
+    def run(self, *, tol=None, max_iter=None, s0=None) -> _Result:
+        """A solve from c, or warm from ``s0``."""
         import jax.numpy as jnp
         dt = self.dtype
-        psi, t = self._solve(
+        warm = s0 is not None
+        psi, s, t = self._solve(
             self.n, self.src, self.dst, jnp.asarray(self.lam, dt),
             jnp.asarray(self.mu, dt),
+            s0 if warm else jnp.zeros((self.n,), dt), jnp.asarray(warm),
             jnp.asarray(self.tol if tol is None else tol, dt),
             jnp.asarray(self.max_iter if max_iter is None else max_iter,
                         jnp.int32))
-        return _Result(psi=psi, iterations=t)
+        return _Result(psi=psi, iterations=t, s=s)
 
 
 class ControlService:
     """What the harness drives, with the service's meaning: ``engine.run``
-    solves cold from c and returns ψ and the iteration count."""
+    solves cold from c and returns ψ and the iteration count; the refresh
+    path patches, re-solves warm and reads, as ``PsiService`` does."""
 
     backend = "control-bfloat16"
 
-    def __init__(self, cfg, n, src, dst, lam, mu, dtype=None):
+    def __init__(self, cfg, n, src, dst, lam, mu, dtype=None, max_iter=None):
         import jax.numpy as jnp
         solver = cfg["solver"]
-        self.tol, self.max_iter = solver["tol"], solver["max_iter"]
+        self.tol = solver["tol"]
+        self.max_iter = max_iter or solver["max_iter"]
         self.engine = _Engine(n, src, dst, lam, mu, self.tol, self.max_iter,
                               dtype or jnp.bfloat16)
+        self.last_result: _Result | None = None
+        self._psi: np.ndarray | None = None
+        self._rank: np.ndarray | None = None
+
+    def update_activity(self, users, lam, mu, *, resolve=True) -> None:
+        self.engine.lam[users] = lam
+        self.engine.mu[users] = mu
+        if resolve:
+            self.resolve()
+
+    def resolve(self) -> None:
+        prev = self.last_result
+        self.last_result = self.engine.run(
+            s0=None if prev is None else prev.s)
+        self._psi = self._rank = None
+
+    def scores(self) -> np.ndarray:
+        if self._psi is None:
+            self._psi = np.asarray(self.last_result.psi, np.float32)
+        return self._psi
+
+    def top_k(self, k):
+        psi = self.scores()
+        ids = np.argsort(-psi, kind="stable")[:k]
+        return ids, psi[ids]
+
+    def rank_of(self, users):
+        if self._rank is None:
+            order = np.argsort(-self.scores(), kind="stable")
+            self._rank = np.empty_like(order)
+            self._rank[order] = np.arange(order.size)
+        return self._rank[np.asarray(users)]
+
+    def scores_batch(self, users):
+        return self.scores()[np.asarray(users)]
 
 
 def main(argv=None) -> int:
@@ -114,6 +160,12 @@ def main(argv=None) -> int:
     ap.add_argument("--program", action="store_true",
                     help="drive the program itself instead, for the lower "
                          "reading of each compared number")
+    ap.add_argument("--max-iter", type=int,
+                    help="cap each of the control's solves at this many "
+                         "iterations instead of the config's max_iter: a "
+                         "bfloat16 iterate stalls at its rounding floor and "
+                         "never meets tol, so at full size every solve "
+                         "would run to max_iter")
     args = ap.parse_args(argv)
     sys.path[:] = [str(ROOT), str(ROOT / "src")] + sys.path[1:]
     from bench import harness
@@ -122,7 +174,10 @@ def main(argv=None) -> int:
         print("control: FAIL: needs a TPU", file=sys.stderr)
         return 2
     jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    make = harness.program_service if args.program else ControlService
+    def control(*a):
+        return ControlService(*a, max_iter=args.max_iter)
+
+    make = harness.program_service if args.program else control
     for seed in args.seeds:
         res = harness.run_cell(
             args.workload, seed=seed, seconds=args.seconds, trace=False,
@@ -131,6 +186,7 @@ def main(argv=None) -> int:
         print("READING " + json.dumps({"workload": args.workload,
                                        "seed": seed,
                                        "program": args.program,
+                                       "max_iter": args.max_iter,
                                        "correct": res["correct"],
                                        "checks": res["checks"]}),
               flush=True)

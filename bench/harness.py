@@ -108,6 +108,11 @@ class Request:
     t_end: float = 0.0
     iterations: int = 0
     psi: np.ndarray | None = None
+    # a refresh's parameters, what its reads answered, and the host
+    # seconds of each of its phases (patch, solve, read)
+    refresh: traffic.Refresh | None = None
+    answers: dict | None = None
+    phase_s: dict | None = None
 
     @property
     def latency_s(self) -> float:
@@ -115,14 +120,20 @@ class Request:
 
 
 class Client:
-    """Sends a mix's requests to the service, one at a time."""
+    """Sends a cold mix's requests to the service, one at a time."""
 
-    def __init__(self, svc, mix: dict):
+    def __init__(self, svc, mix: dict, cfg: dict, n: int, seed: int):
         import jax
         self.svc = svc
         self.mix = mix
         self.annotate = jax.profiler.TraceAnnotation
         self.sent: list[Request] = []
+
+    def warm_up(self) -> None:
+        for _ in range(self.mix["warmup_requests"]):
+            # a cold solve capped at one iteration compiles the same loop
+            # (tol and max_iter are traced arguments), without its cost
+            self.request(timed=False, max_iter=1)
 
     def request(self, *, timed: bool, max_iter: int | None = None
                 ) -> Request:
@@ -136,8 +147,69 @@ class Client:
             r.psi = np.asarray(res.psi)
         r.t_end = time.perf_counter()
         r.iterations = int(res.iterations)
+        r.phase_s = {"solve": r.latency_s}
         self.sent.append(r)
         return r
+
+
+class RefreshClient:
+    """Sends a refresh mix's requests to the service through its public
+    API, one at a time: patch, warm re-solve, reads."""
+
+    def __init__(self, svc, mix: dict, cfg: dict, n: int, seed: int):
+        import jax
+        self.svc = svc
+        self.mix = mix
+        self.gen = traffic.RefreshTraffic(mix, n, cfg["activity"],
+                                          cfg["structure_seed"], seed)
+        self.annotate = jax.profiler.TraceAnnotation
+        self.block = jax.block_until_ready
+        self.sent: list[Request] = []
+
+    def warm_up(self) -> None:
+        """The service's first (cold) solve, then ``warmup_requests``
+        refreshes: every program a refresh runs is compiled after it."""
+        self.svc.resolve()
+        for _ in range(self.mix["warmup_requests"]):
+            self.request(timed=False)
+
+    def request(self, *, timed: bool) -> Request:
+        """One refresh, timed from the patch's submission to the last
+        read's answer; the served ψ is kept by reference."""
+        svc = self.svc
+        r = Request(index=len(self.sent), timed=timed,
+                    refresh=self.gen.request(len(self.sent)))
+        q = r.refresh
+        r.t_start = time.perf_counter()
+        with self.annotate("bench.patch"):
+            svc.update_activity(q.users, q.lam, q.mu, resolve=False)
+        t_patch = time.perf_counter()
+        with self.annotate("bench.solve"):
+            svc.resolve()
+            # ψ is ready inside the solve's span, so that its device time
+            # is the solve's and not the first read's
+            self.block(svc.last_result.psi)
+        t_solve = time.perf_counter()
+        with self.annotate("bench.read"):
+            top_ids, top_vals = svc.top_k(q.top_k)
+            ranks = svc.rank_of(q.rank_users)
+            scores = svc.scores_batch(q.score_users)
+        r.t_end = time.perf_counter()
+        r.iterations = int(svc.last_result.iterations)
+        r.psi = svc.scores()
+        r.answers = {"top_k": (np.asarray(top_ids), np.asarray(top_vals)),
+                     "rank_of": np.asarray(ranks),
+                     "scores_batch": np.asarray(scores)}
+        r.phase_s = {"patch": t_patch - r.t_start, "solve": t_solve - t_patch,
+                     "read": r.t_end - t_solve}
+        self.sent.append(r)
+        return r
+
+
+# each kind of request (``traffic.kind``): the client that sends it and
+# the comparison that checks its answers
+KINDS = {"cold": (Client, checks.compare),
+         "refresh": (RefreshClient, checks.compare_refresh)}
 
 
 class CompileCounter:
@@ -199,11 +271,8 @@ def run_cell(cell: str, *, seed: int, seconds: float, trace: bool,
     if require_tpu and info["interpret"] is not False:
         raise RuntimeError(f"kernels would run in interpret mode: {info}")
 
-    client = Client(svc, mix)
-    for _ in range(mix["warmup_requests"]):
-        # a cold solve capped at one iteration compiles the same loop
-        # (tol and max_iter are traced arguments), without its cost
-        client.request(timed=False, max_iter=1)
+    client = KINDS[traffic.kind(mix)][0](svc, mix, cfg, n, seed)
+    client.warm_up()
     t_ready = time.perf_counter()
     setup_s = t_ready - t_process
     log(setup_s=f"{setup_s:.3f}", generate_s=f"{t_gen - t0:.3f}",
@@ -226,6 +295,8 @@ def run_cell(cell: str, *, seed: int, seconds: float, trace: bool,
         compiles_in_window=counter.compiles,
         cache_loads_in_window=counter.cache_hits,
         generator_s=f"{window_s - sum(r.latency_s for r in window):.3f}")
+    log(**{f"{k}_ms": f"{1e3 * np.mean([r.phase_s[k] for r in window]):.3f}"
+           for k in window[0].phase_s})
 
     traced: list[Request] = []
     reduced = None
@@ -276,7 +347,7 @@ def run_cell(cell: str, *, seed: int, seconds: float, trace: bool,
     return result
 
 
-def trace_segment(client: Client, count: int, out: list[Request]) -> dict:
+def trace_segment(client, count: int, out: list[Request]) -> dict:
     """``count`` more requests with the profiler on; their trace's events."""
     import jax
     tdir = tempfile.mkdtemp(prefix="bench-trace-")
@@ -314,13 +385,13 @@ def check(cfg, mix, sent, n, src, dst, lam, mu, seed):
     """The compared numbers and the count of failed requests."""
     t0 = time.perf_counter()
     ref = Reference(n, src, dst, lam, mu)
+    compare = KINDS[traffic.kind(mix)][1]
     try:
-        numbers = checks.compare(ref, sent, sample_size=mix["check_sample"],
-                                 seed=seed)
+        numbers = compare(ref, sent, sample_size=mix["check_sample"],
+                          seed=seed)
     except ReferenceStalled as e:
         log(reference="stalled", error=str(e))
         return {"reference_stalled": 1}, 0
     log(reference_s=f"{time.perf_counter() - t0:.3f}",
         reference_iterations=ref.iterations)
-    failed = int(numbers["solve_mismatches"])
-    return numbers, failed
+    return numbers, int(numbers.pop("failed"))

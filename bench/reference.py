@@ -66,8 +66,15 @@ class Reference:
         self.n = int(n)
         self.src, self.dst, self.lam, self.mu = src, dst, lam, mu
         self.iterations = 0
+        self._push = None
 
-    def psi(self) -> np.ndarray:
+    def psi(self, lam=None, mu=None) -> np.ndarray:
+        """ψ of the deployment's activity, or of the graph under another
+        ``(lam, mu)``; the push matrix is built once."""
+        if self._push is None:
+            self._push = push_matrix(self.n, self.src, self.dst)
         psi, self.iterations = psi_reference_f64(
-            self.n, self.src, self.dst, self.lam, self.mu)
+            self.n, self.src, self.dst,
+            self.lam if lam is None else lam,
+            self.mu if mu is None else mu, push=self._push)
         return psi

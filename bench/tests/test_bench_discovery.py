@@ -1,5 +1,6 @@
 """Everything BENCHMARK.json names is found by its name, and the file keeps
 to the shape the benchmark's contract gives it."""
+import dataclasses
 import json
 import re
 
@@ -60,13 +61,17 @@ def test_config_loads_by_name(entry):
     assert cfg["assumed"] and cfg["source"]
 
 
+EXACT = {"cold": "solve_mismatches", "refresh": "read_mismatches"}
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_cell_files_load_by_name(cell):
     entry, cfg, mix, limits = harness.cell_files(BENCHMARK, cell)
     assert entry["config"] == cfg["name"]
     traffic.validate(mix)
-    assert set(limits) == {"psi_node_rel", "solve_mismatches"}
-    assert limits["solve_mismatches"]["limit"] == 0
+    exact = EXACT[traffic.kind(mix)]
+    assert set(limits) == {"psi_node_rel", exact}
+    assert limits[exact]["limit"] == 0
     psi = limits["psi_node_rel"]
     # set between the program's largest reading and the control's least
     assert psi["lower"] < psi["limit"] < psi["upper"]
@@ -90,7 +95,9 @@ def test_every_cell_reports_what_its_layers_move(cell):
 
 def _run(trace=None):
     reqs = [Request(index=i, timed=True, t_start=i, t_end=i + lat,
-                    iterations=it)
+                    iterations=it,
+                    phase_s={"patch": 0.01 * lat, "solve": 0.5 * lat,
+                             "read": 0.1 * lat})
             for i, (lat, it) in enumerate([(0.9, 12), (1.1, 14), (1.0, 13)])]
     return Run(n=100, m=1000, setup_s=5.0, window_s=3.0,
                requests=reqs, traced=reqs[:1], trace=trace, peak=None)
@@ -102,6 +109,29 @@ def test_readers_on_a_synthetic_run():
     assert read["setup_s"] == 5.0
     assert read["solve_s"] == pytest.approx(1.0)
     assert read["iterations.cold"] == pytest.approx(13.0)
+    assert read["refresh_s"] == pytest.approx(1.0)
+    assert read["iterations.refresh"] == pytest.approx(13.0)
+    assert read["patch_ms.refresh"] == pytest.approx(10.0)
+    assert read["read_ms.refresh"] == pytest.approx(100.0)
     # no trace: the trace's metrics are left out, never 0
-    for name in ("psi_step_roofline.cold", "idle_share.cold"):
+    for name in ("psi_step_roofline.cold", "idle_share.cold",
+                 "psi_step_roofline.refresh", "idle_share.refresh"):
         assert read[name] is None
+
+
+def test_refresh_trace_readers_on_a_synthetic_trace():
+    """The refresh cell's device metrics: the idle share of the traced
+    window, and the least time of the traced pushes over the device time
+    inside ``bench.solve``."""
+    from bench import tracing, work
+    reduced = tracing.Reduced(window_s=0.5, busy_s=0.2, chips=1,
+                              device_s_in={"bench.solve": 0.1,
+                                           "bench.read": 0.05},
+                              op_seconds=[], idle_gaps=[])
+    run = dataclasses.replace(_run(reduced), peak=work.peaks("TPU v5 lite"))
+    idle = harness.reader("idle_share.refresh")(run)
+    assert idle == pytest.approx(60.0)
+    roof = harness.reader("psi_step_roofline.refresh")(run)
+    pushes = run.traced[0].iterations + 1
+    assert roof == pytest.approx(
+        100 * pushes * (4 * 1000 + 24 * 100) / 819e9 / 0.1)
