@@ -20,6 +20,21 @@ import numpy as np
 __all__ = ["Graph"]
 
 
+def _sort_by(keys: np.ndarray, other: np.ndarray):
+    """``(keys[o], other[o])`` for ``o = np.argsort(keys, kind="stable")``
+    over node ids (int32, ≥ 0), by one plain sort of ``key << 32 |
+    position``: every packed key is distinct, so the order is the stable
+    one, the sorted keys come out of the packed ones, and NumPy's
+    vectorised int64 sort takes about a fifth of the time of its stable
+    argsort over tens of millions of edges."""
+    packed = keys.astype(np.int64) << 32
+    packed |= np.arange(keys.size, dtype=np.int64)
+    packed.sort()
+    other = other[packed & 0xFFFFFFFF]
+    packed >>= 32
+    return packed.astype(np.int32), other
+
+
 @dataclasses.dataclass(frozen=True)
 class Graph:
     """Immutable directed graph held in host (numpy) memory.
@@ -60,26 +75,17 @@ class Graph:
         """#followers of each node."""
         return np.bincount(self.dst, minlength=self.n).astype(np.int32)
 
-    # -- sorted views --------------------------------------------------- #
-    @cached_property
-    def _dst_order(self) -> np.ndarray:
-        return np.argsort(self.dst, kind="stable")
-
-    @cached_property
-    def _src_order(self) -> np.ndarray:
-        return np.argsort(self.src, kind="stable")
-
+    # -- sorted views (ties in input order) ----------------------------- #
     @cached_property
     def edges_by_dst(self) -> tuple[np.ndarray, np.ndarray]:
         """(src, dst) with dst ascending — scatter-friendly for left matvec."""
-        o = self._dst_order
-        return self.src[o], self.dst[o]
+        dst, src = _sort_by(self.dst, self.src)
+        return src, dst
 
     @cached_property
     def edges_by_src(self) -> tuple[np.ndarray, np.ndarray]:
         """(src, dst) with src ascending — for right matvec / sampling."""
-        o = self._src_order
-        return self.src[o], self.dst[o]
+        return _sort_by(self.src, self.dst)
 
     @cached_property
     def csr_indptr(self) -> np.ndarray:

@@ -114,8 +114,8 @@ def estimate_edge_tile_cost(graph: Graph, *, tile: int, e1: int, e2: int,
     """Modeled HBM bytes per fused step under the edge-tile regime."""
     eblk = e1 * e2
     num_tiles = max(1, -(-graph.n // tile))
-    _, dst = graph.edges_by_dst
-    counts = np.bincount(dst // tile, minlength=num_tiles)
+    _, dst = graph.edges_by_dst          # ascending: count by tile bounds
+    counts = np.diff(np.searchsorted(dst, np.arange(num_tiles + 1) * tile))
     blocks = np.maximum(1, -(-counts // eblk))
     padded_slots = float(blocks.sum()) * eblk
     return padded_slots * slot_bytes + num_tiles * tile * node_bytes
@@ -322,10 +322,9 @@ def plan_regime(graph: Graph, *, microbench: bool = False,
     # would spuriously invalidate warm re-prepares (the no-replan/
     # no-retrace contract of test_engine.py) every time a sample lands.
     cal_sig = None
-    if cal is not None:
-        m0 = cal.multipliers({"edge_tile", "bsr"})
-        if len(set(m0.values())) > 1:
-            cal_sig = cal.generation
+    m0 = {} if cal is None else cal.multipliers({"edge_tile", "bsr"})
+    if len(set(m0.values())) > 1:
+        cal_sig = cal.generation
 
     key = None
     if cache is not None:
@@ -342,11 +341,32 @@ def plan_regime(graph: Graph, *, microbench: bool = False,
                     measured_us=hit.measured_us, chosen=True)])
             return hit
 
-    # Density gate: drop BSR parameterizations whose tiles would stream
-    # mostly zero-fill. Deterministic (structure-only), so it is safe under
-    # the cache key above — the same graph always prunes the same set.
+    edge_plans = [
+        RegimePlan(regime="edge_tile", tile=t, e1=a, e2=b,
+                   est_bytes=estimate_edge_tile_cost(
+                       graph, tile=t, e1=a, e2=b,
+                       slot_bytes=eb, node_bytes=nb))
+        for t, a, b in edge_tile_candidates]
+    best_edge = min((p.est_bytes * m0.get("edge_tile", 1.0)
+                     for p in edge_plans), default=float("inf"))
+
+    # Two gates drop BSR parameterizations before scoring or timing, both
+    # structure-only, so safe under the cache key above. The byte floor
+    # (model planning only; timed planning lets the clock decide): a BSR
+    # plan streams at least one ts×td block per destination tile, and
+    # where that alone, under the calibration factors, costs more than the
+    # cheapest edge-tile plan, the model can never pick it, so its
+    # occupancy (a sort over M) is not computed. The density gate: tiles
+    # that would stream mostly zero-fill.
     dense_bsr, pruned = [], []
     for ts, td in bsr_candidates:
+        ndt = max(1, -(-graph.n // td))
+        floor = ndt * ts * td * bb + ndt * td * nb
+        if not microbench and floor * m0.get("bsr", 1.0) > best_edge:
+            pruned.append(obs_explain.Pruned(
+                f"bsr(ts={ts},td={td})", "BSR_BYTE_FLOOR",
+                detail=dict(est_at_least=float(floor))))
+            continue
         occ = bsr_occupancy(graph, ts=ts, td=td)
         if occ >= BSR_MIN_OCCUPANCY:
             dense_bsr.append((ts, td))
@@ -356,13 +376,7 @@ def plan_regime(graph: Graph, *, microbench: bool = False,
                 detail=dict(occupancy=round(occ, 6),
                             floor=BSR_MIN_OCCUPANCY)))
 
-    candidates = [
-        RegimePlan(regime="edge_tile", tile=t, e1=a, e2=b,
-                   est_bytes=estimate_edge_tile_cost(
-                       graph, tile=t, e1=a, e2=b,
-                       slot_bytes=eb, node_bytes=nb))
-        for t, a, b in edge_tile_candidates
-    ] + [
+    candidates = edge_plans + [
         RegimePlan(regime="bsr", ts=ts, td=td,
                    est_bytes=estimate_bsr_cost(graph, ts=ts, td=td,
                                                slot_bytes=bb, node_bytes=nb))

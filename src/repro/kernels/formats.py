@@ -61,21 +61,20 @@ def build_edge_tiles(graph: Graph, *, tile: int = 256, e1: int = 8,
     num_tiles = max(1, -(-n // tile))
     n_pad = num_tiles * tile
     src, dst = graph.edges_by_dst
-    tile_of_edge = dst // tile
-    counts = np.bincount(tile_of_edge, minlength=num_tiles)
+    # dst ascending: each node tile's edges are one run of the list
+    bounds = np.searchsorted(dst, np.arange(num_tiles + 1) * tile)
+    counts = np.diff(bounds)
     blocks_per_tile = np.maximum(1, -(-counts // eblk))
-    padded = blocks_per_tile * eblk
-    offsets = np.concatenate([[0], np.cumsum(padded)])[:-1]
-    total = int(padded.sum())
+    offsets = np.concatenate([[0], np.cumsum(blocks_per_tile * eblk)])
 
-    flat_src = np.full(total, n, np.int32)            # sentinel: s_pre[n] == 0
-    flat_dstl = np.zeros(total, np.int32)
-    # position of each edge inside its tile's padded span
-    tile_start_edge = np.concatenate([[0], np.cumsum(counts)])[:-1]
-    pos_in_tile = np.arange(graph.m) - tile_start_edge[tile_of_edge]
-    slot = offsets[tile_of_edge] + pos_in_tile
-    flat_src[slot] = src
-    flat_dstl[slot] = dst - tile_of_edge * tile
+    flat_src = np.full(offsets[-1], n, np.int32)      # sentinel: s_pre[n] == 0
+    flat_dstl = np.zeros(offsets[-1], np.int32)
+    # copy each tile's run to the head of its padded span
+    for t, (a, b, o) in enumerate(zip(bounds[:-1].tolist(),
+                                      bounds[1:].tolist(),
+                                      offsets[:-1].tolist())):
+        flat_src[o:o + b - a] = src[a:b]
+        np.subtract(dst[a:b], t * tile, out=flat_dstl[o:o + b - a])
 
     num_blocks = int(blocks_per_tile.sum())
     src_idx = flat_src.reshape(num_blocks, e1, e2)

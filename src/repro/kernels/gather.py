@@ -3,10 +3,10 @@
 The edge-tile step needs each edge slot's source value ``(s ⊙ 1/w)[src]``
 before its one-hot scatter. An XLA gather reads each slot at random from
 HBM, one 4-byte load per slot, and is bound by per-element latency. The
-source vector is small (8.4 MB at 2.1M users), so this kernel holds all of
-it in VMEM for the whole call, laid out as ``[n_gather // 128, 128]``, and
-gathers there, one edge block per grid step and one row of 128 slots at a
-time:
+source vector is small (8.4 MB at 2.1M users, 33.5 MB at 8.4M), so this
+kernel holds all of it in VMEM for the whole call, laid out as
+``[n_gather // 128, 128]``, and gathers there, one edge block per grid step
+and one row of 128 slots at a time:
 
   1. for each slot ``j`` of the row, read its index from SMEM and load the
      source row ``idx >> 7`` (one single-sublane vector load) into row ``j``
@@ -37,10 +37,14 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["VMEM_SOURCE_BUDGET", "fits_vmem", "gather_call"]
 
 LANES = 128
-# bytes of gather source a kernel call may keep resident in VMEM (v5e has
-# 128 MiB per core); 32 MiB holds the source of ~8.4M users in float32
-VMEM_SOURCE_BUDGET = 32 * 1024 * 1024
+_V5E_VMEM = 128 * 1024 * 1024          # per core
+# VMEM a call asks for beyond the source: the index and output blocks,
+# double-buffered (16 KiB at e1 = 8), the [128, 128] scratch (64 KiB) and
+# Mosaic's own
 _VMEM_SLACK = 8 * 1024 * 1024
+# bytes of gather source a kernel call may keep resident in VMEM: all of
+# v5e's VMEM less the slack, 120 MiB, the source of ~31.4M users in float32
+VMEM_SOURCE_BUDGET = _V5E_VMEM - _VMEM_SLACK
 
 
 def fits_vmem(n_gather: int, e2: int, dtype) -> bool:

@@ -19,7 +19,7 @@ from .formats import BsrFormat, EdgeTileFormat, build_bsr, build_edge_tiles
 from .edge_spmv import edge_spmv_call
 from .bsr_spmv import bsr_spmv_call
 from .gather import fits_vmem, gather_call
-from .power_step import power_step_call
+from .power_step import call_starts, power_step_call
 from .seg_mm import seg_mm_call
 
 __all__ = [
@@ -49,6 +49,9 @@ class DeviceEdgeTiles:
     block_tile: jax.Array
     block_first: jax.Array
     block_last: jax.Array
+    # first blocks of a step's second and later psi_power_step calls
+    # (kernels/power_step.py call_starts); empty for one call
+    call_starts: tuple[int, ...] = ()
 
     @classmethod
     def from_format(cls, fmt: EdgeTileFormat) -> "DeviceEdgeTiles":
@@ -60,7 +63,8 @@ class DeviceEdgeTiles:
             dst_local=jnp.asarray(fmt.dst_local),
             block_tile=jnp.asarray(fmt.block_tile),
             block_first=jnp.asarray(fmt.block_first),
-            block_last=jnp.asarray(fmt.block_last))
+            block_last=jnp.asarray(fmt.block_last),
+            call_starts=call_starts(fmt.block_tile))
 
     def pad_gather_source(self, s_pre: jax.Array) -> jax.Array:
         """f[n] → f[1, n_gather] with zeros beyond n (sentinel = n)."""
@@ -81,7 +85,8 @@ jax.tree_util.register_dataclass(
     DeviceEdgeTiles,
     data_fields=["src_idx", "dst_local", "block_tile", "block_first",
                  "block_last"],
-    meta_fields=["n", "n_pad", "n_gather", "tile", "e1", "e2", "num_tiles"])
+    meta_fields=["n", "n_pad", "n_gather", "tile", "e1", "e2", "num_tiles",
+                 "call_starts"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -177,7 +182,9 @@ def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
                interpret: bool | None = None) -> tuple[jax.Array, jax.Array]:
     """One fused Alg. 2 step on padded [1, n_pad] node vectors: the edge
     slots' sources gathered by :func:`gather_step_edges`, then the
-    ``psi_power_step`` kernel's scatter, μ/c epilogue and gap.
+    ``psi_power_step`` kernel's scatter, μ/c epilogue and gap, in one call
+    or, past the SMEM tables' budget, in one call per block range that
+    ``fmt.call_starts`` opens (the gauge ``psi_power_step_calls``).
 
     Args:
       s: f[1, n_pad] current series vector (padded layout).
@@ -191,11 +198,22 @@ def power_step(s: jax.Array, inv_w_gather: jax.Array, mu_pad: jax.Array,
         s_pre = jnp.pad(s, ((0, 0), (0, fmt.n_gather - fmt.n_pad))) \
             * inv_w_gather
         vals = gather_step_edges(s_pre, fmt, interpret)
-    s_new, gap = power_step_call(
-        vals, fmt.dst_local, fmt.block_tile,
-        fmt.block_first, fmt.block_last, mu_pad, c_pad, s,
-        tile=fmt.tile, e1=fmt.e1, e2=fmt.e2, num_tiles=fmt.num_tiles,
-        interpret=interpret)
+    tables = (fmt.block_tile, fmt.block_first, fmt.block_last)
+    bounds = (0, *fmt.call_starts, tables[0].shape[-1])
+    obs_metrics.gauge("psi_power_step_calls",
+                      "psi_power_step kernel calls per edge-tile step") \
+        .set(len(bounds) - 1)
+    carry = None
+    with jax.named_scope("psi.power_step"):
+        for lo, hi in zip(bounds[:-1], bounds[1:]):
+            own = (tables if not fmt.call_starts
+                   else tuple(t[lo:hi] for t in tables))
+            carry = power_step_call(
+                vals, fmt.dst_local, *own, mu_pad, c_pad, s, carry,
+                tile=fmt.tile, e1=fmt.e1, e2=fmt.e2,
+                num_tiles=fmt.num_tiles, first_block=lo,
+                interpret=interpret)
+    s_new, gap = carry
     return s_new, gap[0, 0]
 
 

@@ -21,8 +21,9 @@ poisson event log → online rate estimation → PsiService queries):
    (``/healthz``, ``/slo``) answer on an ephemeral port.
 6. **decision observability** — :func:`repro.kernels.autotune.plan_regime`
    records a full :class:`~repro.obs.explain.DecisionRecord` (candidate
-   table, ``BSR_MIN_OCCUPANCY`` prunes, ``PLAN_CACHE`` hit/miss), the
-   plan-cache counters land in the registry, and
+   table, ``BSR_BYTE_FLOOR`` and ``BSR_MIN_OCCUPANCY`` prunes,
+   ``PLAN_CACHE`` hit/miss), the plan-cache counters land in the
+   registry, and
    ``PsiService.explain()`` renders the EXPLAIN-ANALYZE tree.
 7. **calibration loop** — the acceptance drill: skewed cost-model
    constants (injected via ``slot_bytes``) make the uncalibrated planner
@@ -241,8 +242,9 @@ def run_check(out_dir: str, *, events: int = 1_200) -> list[str]:
               and rec.source == "model",
               "plan_regime miss records the full candidate table")
         check(bool(rec.pruned)
-              and all(p.reason == "BSR_MIN_OCCUPANCY" for p in rec.pruned),
-              f"density gate prunes carry their reason "
+              and all(p.reason in ("BSR_BYTE_FLOOR", "BSR_MIN_OCCUPANCY")
+                      for p in rec.pruned),
+              f"byte-floor and density-gate prunes carry their reason "
               f"({len(rec.pruned or ())} pruned)")
         autotune.plan_regime(g6, cache=cache, calibration=None)
         rec2 = obs_explain.get_log().last(kind="regime_plan")

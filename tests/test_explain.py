@@ -136,10 +136,12 @@ def test_plan_regime_records_candidates_prunes_and_cache(fresh_obs,
     assert rec.source == "model" and plan.source == "model"
     assert sum(c.chosen for c in rec.candidates) == 1
     assert len(rec.candidates) >= 2          # alternatives kept, not just winner
-    # hyper-sparse graph: every BSR parameterization is density-gated
-    assert rec.pruned and all(p.reason == "BSR_MIN_OCCUPANCY"
+    # hyper-sparse graph: every BSR parameterization is pruned before its
+    # occupancy pass, since a block per destination tile already costs
+    # more than the chosen edge-tile plan
+    assert rec.pruned and all(p.reason == "BSR_BYTE_FLOOR"
                               for p in rec.pruned)
-    assert all(p.detail["occupancy"] < autotune.BSR_MIN_OCCUPANCY
+    assert all(p.detail["est_at_least"] > plan.est_bytes
                for p in rec.pruned)
 
     autotune.plan_regime(sparse_graph, cache=cache, calibration=None)
@@ -158,6 +160,20 @@ def test_plan_cache_size_gauge_tracks_global_cache_only(fresh_obs,
     autotune.plan_regime(sparse_graph, cache=autotune.PlanCache(),
                          calibration=None)
     assert fresh_obs.value("psi_plan_cache_size") == before + 1
+
+
+def test_timed_planning_records_the_density_gate(fresh_obs, sparse_graph,
+                                                 monkeypatch):
+    """Timed plans skip the byte floor, so the density gate prunes the
+    hyper-sparse graph's BSR candidates, each with its occupancy."""
+    monkeypatch.setattr(autotune, "_microbench_step", _fake_bench_bsr_wins)
+    autotune.plan_regime(sparse_graph, cache=None, microbench=True,
+                         calibration=None)
+    rec = obs_explain.get_log().last(kind="regime_plan")
+    assert rec.pruned and all(p.reason == "BSR_MIN_OCCUPANCY"
+                              for p in rec.pruned)
+    assert all(p.detail["occupancy"] < autotune.BSR_MIN_OCCUPANCY
+               for p in rec.pruned)
 
 
 def test_microbench_sets_source_and_feeds_store(fresh_obs, blocky_graph,

@@ -110,6 +110,8 @@ def test_over_the_vmem_budget_the_xla_gather_stays(registry, monkeypatch):
 
 @pytest.mark.parametrize("n_gather,e2,dtype,fits", [
     (2_097_280, 128, jnp.float32, True),            # Graph500 scale 21
+    (8_388_736, 128, jnp.float32, True),            # scale 23, 33.5 MB
+    (16_777_344, 128, jnp.float32, True),           # scale 24, 67.1 MB
     (gather.VMEM_SOURCE_BUDGET // 4, 128, jnp.float32, True),
     (gather.VMEM_SOURCE_BUDGET // 4 + 128, 128, jnp.float32, False),
     (2_097_280, 64, jnp.float32, False),            # not a lane-wide row
@@ -117,3 +119,9 @@ def test_over_the_vmem_budget_the_xla_gather_stays(registry, monkeypatch):
 ])
 def test_fits_vmem_is_a_shape_test(n_gather, e2, dtype, fits):
     assert fits_vmem(n_gather, e2, dtype) is fits
+
+
+def test_vmem_budget_is_the_chips_vmem_less_the_slack():
+    """A call asks for the source and the slack: at the budget, all of
+    v5e's 128 MiB of VMEM a core."""
+    assert gather.VMEM_SOURCE_BUDGET + gather._VMEM_SLACK == 128 * 2**20

@@ -108,6 +108,20 @@ def test_generator_properties(n, seed):
     assert np.unique(key).size == g.m           # no duplicate edges
 
 
+@given(st.integers(1, 300), st.integers(0, 2_000), st.integers(0, 10_000))
+@settings(max_examples=20, deadline=None)
+def test_sorted_views_are_the_stable_orders(n, m, seed):
+    """Both sorted edge views list the edges in a stable argsort's order,
+    ties (many edges to one user) in their input order."""
+    rng = np.random.default_rng(seed)
+    src, dst = rng.integers(0, n, (2, m)).astype(np.int32)
+    g = Graph(n, src, dst)
+    for (s, d), key in ((g.edges_by_dst, dst), (g.edges_by_src, src)):
+        o = np.argsort(key, kind="stable")
+        np.testing.assert_array_equal(s, src[o])
+        np.testing.assert_array_equal(d, dst[o])
+
+
 @given(st.lists(st.floats(0.01, 10.0), min_size=3, max_size=30),
        st.integers(0, 999))
 @settings(max_examples=20, deadline=None)

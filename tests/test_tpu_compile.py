@@ -3,9 +3,9 @@
 Interpret mode (every other kernel test) cannot see what Mosaic refuses:
 gathers it cannot lower, scalar stores to VMEM, blocks that overflow VMEM or
 SMEM. These tests lower the kernels of the main path at the width of the
-paper's Twitter graph for one chip of a described ``v5e:2x2`` topology and
-compile them with the TPU compiler. Nothing runs, so they say nothing about
-results or times.
+paper's Twitter graph and at the benchmark cells' shapes for one chip of a
+described ``v5e:2x2`` topology and compile them with the TPU compiler.
+Nothing runs, so they say nothing about results or times.
 
 The topology is described inside a module fixture, never at import: only one
 process may load the TPU library, and under pytest-xdist every worker imports
@@ -16,6 +16,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -25,8 +26,8 @@ from repro.kernels.autotune import BSR_CANDIDATES, EDGE_TILE_CANDIDATES
 from repro.kernels.bsr_spmv import bsr_spmv_call
 from repro.kernels.edge_spmv import edge_spmv_call
 from repro.kernels.gather import fits_vmem, gather_call
-from repro.kernels.ops import DeviceEdgeTiles
-from repro.kernels.power_step import power_step_call
+from repro.kernels.ops import DeviceEdgeTiles, power_step
+from repro.kernels.power_step import call_starts, power_step_call
 
 # the paper's Twitter graph (Table II), the largest on the ψ serving path
 N, M = 465_017, 834_797
@@ -77,8 +78,14 @@ EDGE_TILE_SHAPES = [
     pytest.param(t, a, b, N, _twitter_blocks(t, a, b), id=f"twitter-{t}")
     for t, a, b in EDGE_TILE_CANDIDATES
 ] + [pytest.param(256, 8, 128, 1 << 21, 36_163, id="rmat21-256")]
-# the benchmark cell's plan: Graph500 scale 21 at tile 512, 33,706 blocks
+# the benchmark cells' plans: Graph500 scale 21 at tile 512, 33,706 blocks,
+# and scale 23, 136,268 blocks, whose scalar-prefetch tables (1.57 MiB)
+# outgrow SMEM in one call and whose 33.5 MB gather source stays in VMEM
 CELL_SHAPE = pytest.param(512, 8, 128, 1 << 21, 33_706, id="g500-s21-512")
+S23_SHAPE = pytest.param(512, 8, 128, 1 << 23, 136_268, id="g500-s23-512")
+# the split step: scale 23's cell, and a block count of scale 24 (~300k)
+SPLIT_SHAPES = [S23_SHAPE,
+                pytest.param(512, 8, 128, 1 << 24, 300_000, id="scale24-512")]
 
 
 def _compile(fn, *args):
@@ -106,7 +113,38 @@ def test_power_step_compiles(sd, tile, e1, e2, n, nb):
     _assert_kernel(compiled, "psi_power_step")
 
 
-@pytest.mark.parametrize("tile,e1,e2,n,nb", EDGE_TILE_SHAPES + [CELL_SHAPE])
+@pytest.mark.parametrize("tile,e1,e2,n,nb", SPLIT_SHAPES)
+def test_split_power_step_compiles(sd, tile, e1, e2, n, nb):
+    """The whole step at a block count past one call's SMEM: the gather
+    kernel, then one ``psi_power_step`` call per tile-aligned block range,
+    each under the ``psi.power_step`` scope."""
+    num_tiles = -(-n // tile)
+    per_tile = np.full(num_tiles, nb // num_tiles)
+    per_tile[:nb - per_tile.sum()] += 1
+    starts = call_starts(np.repeat(np.arange(num_tiles, dtype=np.int32),
+                                   per_tile))
+    assert len(starts) >= 2
+    blocks = sd((nb,), I32)
+    fmt = DeviceEdgeTiles(
+        n=n, n_pad=num_tiles * tile, n_gather=num_tiles * tile + 128,
+        tile=tile, e1=e1, e2=e2, num_tiles=num_tiles,
+        src_idx=sd((nb, e1, e2), I32), dst_local=sd((nb, e1, e2), I32),
+        block_tile=blocks, block_first=blocks, block_last=blocks,
+        call_starts=starts)
+    vec = sd((1, fmt.n_pad), F32)
+    compiled = _compile(
+        lambda s, w, mu, c, f: power_step(s, w, mu, c, f, interpret=False),
+        vec, sd((1, fmt.n_gather), F32), vec, vec, fmt)
+    _assert_kernel(compiled, "psi_power_step")
+    _assert_kernel(compiled, "psi_gather")
+    text = compiled.as_text()
+    calls = set(re.findall(r"%(psi_power_step(?:\.\d+)?) = ", text))
+    assert len(calls) == len(starts) + 1
+    assert "psi.power_step/jit(power_step_call)/psi_power_step/" in text
+
+
+@pytest.mark.parametrize("tile,e1,e2,n,nb",
+                         EDGE_TILE_SHAPES + [CELL_SHAPE, S23_SHAPE])
 def test_gather_compiles(sd, tile, e1, e2, n, nb):
     """The whole gather source resident in VMEM, the slot indices streamed."""
     n_gather = -(-n // tile) * tile + 128
